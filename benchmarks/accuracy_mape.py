@@ -35,6 +35,8 @@ import os
 import shutil
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import DATASETS_DIR, write_json
 
 BASELINE_PATH = os.path.join(os.path.dirname(__file__), "baselines",
@@ -138,6 +140,7 @@ def run(n_graphs: int = 0, max_epochs: int = 0, workers: int = 0,
 
 
 def main() -> None:
+    enable_compile_cache()
     full = "--full" in sys.argv
     if "--print-plan-hash" in sys.argv:
         # CI uses this as the actions/cache key for artifacts/datasets so

@@ -33,6 +33,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import write_json
 
 FORCE_DEVICES = 4
@@ -199,6 +201,7 @@ def run(n_requests: int = 512, poison_every: int = 64, replicas: int = 2,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     ch, na, cl = res["chaos_bisect"], res["chaos_failbin"], res["clean"]
     print(f"host    : {res['n_cores']} cores, {res['n_devices']} jax "

@@ -9,6 +9,15 @@ from typing import Dict, List, Optional
 ART = os.environ.get("REPRO_BENCH_DIR", "artifacts/bench")
 
 
+def bench_hardware():
+    """Roofline envelope for this run: the device's published peaks, or
+    on a CPU the nominal ``CPU_HOST`` envelope, asked for by name."""
+    import jax
+
+    from repro.roofline.analysis import CPU_HOST, default_hardware
+    return CPU_HOST if jax.default_backend() == "cpu" else default_hardware()
+
+
 def art_path(name: str) -> str:
     os.makedirs(ART, exist_ok=True)
     return os.path.join(ART, name)

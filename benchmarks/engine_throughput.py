@@ -24,6 +24,8 @@ paths consume the same pre-built ``OpGraph`` list.
 """
 from __future__ import annotations
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import timed, write_json
 
 
@@ -113,6 +115,7 @@ def run(n_graphs: int = 64, hidden: int = 128, repeats: int = 3):
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print(f"eager  : {res['eager_pred_per_s']:9.2f} predictions/s "
           f"(pre-engine batch-of-1 baseline)")

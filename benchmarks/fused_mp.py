@@ -39,7 +39,9 @@ import json
 import os
 import sys
 
-from .common import timed, write_json
+from repro.compile_cache import enable_compile_cache
+
+from .common import bench_hardware, timed, write_json
 from .packed_batching import _mixed_zoo
 
 VARIANTS = ("graphsage", "gcn", "gat", "gin", "mlp")
@@ -68,7 +70,10 @@ def _equivalence(samples, hidden: int):
     import jax.numpy as jnp
     import numpy as np
     from repro.core.batching import collate_packed
+    from unittest import mock
+
     from repro.core.gnn import PMGNSConfig, pmgns_infer, pmgns_init
+    from repro.kernels import ops
 
     out = {"ref": {}, "pallas": {}}
     for variant in VARIANTS:
@@ -84,15 +89,8 @@ def _equivalence(samples, hidden: int):
         # forced Pallas megakernel (interpret mode on CPU) vs the same
         # composed lax baseline
         cfg_pl = dataclasses.replace(cfg_on, use_pallas=True)
-        env = os.environ.get("REPRO_KERNEL_IMPL")
-        os.environ["REPRO_KERNEL_IMPL"] = "pallas"
-        try:
+        with mock.patch.object(ops, "kernel_impl", lambda: "pallas"):
             y_pl = np.asarray(pmgns_infer(params, cfg_pl, bp))
-        finally:
-            if env is None:
-                os.environ.pop("REPRO_KERNEL_IMPL", None)
-            else:
-                os.environ["REPRO_KERNEL_IMPL"] = env
         out["pallas"][variant] = float(np.abs(y_off - y_pl).max())
     return out
 
@@ -222,7 +220,7 @@ def _modeled_traffic(samples, hidden: int):
                    "wall_us": round(wall * 1e6),
                    "note": ("full-bin forward wall; traffic summed over "
                             f"{n_layers} MP layers")}
-            row.update(achieved_rates(fl, by, wall))
+            row.update(achieved_rates(fl, by, wall, bench_hardware()))
             rows.append(row)
     return {"full_bin": dict(FULL_BIN), "traffic_ratio": ratios,
             "fused_modeled_bytes": fused_bytes, "rows": rows}
@@ -356,6 +354,7 @@ def run(n_graphs: int = 192, hidden: int = 64, repeats: int = 4,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     st, bk = res["stream"], res["bulk"]
     print(f"stream : unfused {st['unfused_pred_per_s']:8.2f}/s  fused "

@@ -20,7 +20,6 @@ from repro.core.gnn import PMGNSConfig, pmgns_apply, pmgns_init
 from repro.core.node_features import node_feature_matrix
 from repro.core.tracer import trace_graph
 from repro.kernels import ops, ref
-from repro.kernels.sage_spmm import sage_aggregate_pallas
 from repro.roofline.analysis import (achieved_rates, dense_aggregate_traffic,
                                      edge_softmax_traffic,
                                      mp_layer_traffic,
@@ -28,12 +27,13 @@ from repro.roofline.analysis import (achieved_rates, dense_aggregate_traffic,
                                      segment_readout_traffic)
 from repro.zoo.families import build_family
 
-from .common import timed, write_json
+from .common import bench_hardware, timed, write_json
 
 
 def _rate_row(name: str, derived: str, wall_s: float, traffic):
     """One kernel row with achieved GB/s + %-of-roofline columns."""
-    r = achieved_rates(traffic["flops"], traffic["bytes"], wall_s)
+    r = achieved_rates(traffic["flops"], traffic["bytes"], wall_s,
+                       bench_hardware())
     return {"name": name, "us_per_call": round(wall_s * 1e6),
             "derived": derived,
             "gb_s": round(r["achieved_gb_s"], 2),
@@ -67,8 +67,8 @@ def run():
     rows.append({"name": "pmgns_forward_b1", "us_per_call":
                  round(t_fwd * 1e6), "derived": "hidden=512"})
 
-    # kernels: ref vs interpret-mode pallas, with achieved-GB/s columns
-    # from the roofline traffic models
+    # kernels: ref vs pallas (interpret mode off a TPU), with
+    # achieved-GB/s columns from the roofline traffic models
     adj = jnp.asarray((rng.random((4, 256, 256)) < 0.05), jnp.float32)
     h = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
     r = jax.jit(ref.sage_aggregate_ref)
@@ -76,12 +76,14 @@ def run():
     _, t_ref = timed(lambda: r(adj, h).block_until_ready(), repeats=5)
     rows.append(_rate_row("sage_ref_jit", "B4xN256xF64", t_ref,
                           dense_aggregate_traffic(4, 256, 64)))
-    out = sage_aggregate_pallas(adj, h)
-    _, t_pl = timed(lambda: sage_aggregate_pallas(adj, h).block_until_ready(),
-                    repeats=2)
-    rows.append(_rate_row("sage_pallas_interpret",
-                          "correctness-mode (CPU interpret)", t_pl,
-                          dense_aggregate_traffic(4, 256, 64)))
+    ops.sage_aggregate(adj, h, impl="pallas").block_until_ready()
+    _, t_pl = timed(lambda: ops.sage_aggregate(
+        adj, h, impl="pallas").block_until_ready(), repeats=2)
+    on_tpu = jax.default_backend() == "tpu"
+    rows.append(_rate_row(
+        "sage_pallas" if on_tpu else "sage_pallas_interpret",
+        "B4xN256xF64" if on_tpu else "correctness-mode (CPU interpret)",
+        t_pl, dense_aggregate_traffic(4, 256, 64)))
 
     # sparse / packed kernels at a full-bin-ish shape
     b, e, n, f, hd, p, g = 4, 1024, 512, 64, 4, 4096, 256

@@ -29,6 +29,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import timed, write_json
 
 VARIANTS = ("graphsage", "gcn", "gat", "gin", "mlp")
@@ -217,6 +219,7 @@ def run(n_graphs: int = 192, hidden: int = 64, repeats: int = 4,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     st, bk = res["stream"], res["bulk"]
     print(f"stream : sparse {st['sparse_pred_per_s']:8.2f}/s  packed "

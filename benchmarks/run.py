@@ -10,10 +10,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import sys
 import time
+
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
@@ -55,12 +59,14 @@ def main() -> None:
         jobs = {k: v for k, v in jobs.items() if k == args.only}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, job in jobs.items():
         t0 = time.perf_counter()
         try:
             out = job()
         except Exception as e:  # pragma: no cover
             print(f"{name},ERROR,{type(e).__name__}: {e}")
+            failed.append(name)
             continue
         dt = time.perf_counter() - t0
         if name == "microbench":
@@ -71,6 +77,8 @@ def main() -> None:
                    if k not in ("rows", "artifact")}
         print(f"{name},{round(dt * 1e6)},"
               f"\"{json.dumps(derived, default=str)[:160]}\"")
+    if failed:
+        sys.exit(f"jobs that raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

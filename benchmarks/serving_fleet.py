@@ -34,6 +34,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import write_json
 
 FORCE_DEVICES = 4
@@ -241,6 +243,7 @@ def run(n_unique: int = 24, n_requests: int = 720, hidden: int = 384,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print(f"host   : {res['n_cores']} cores, {res['n_devices']} jax "
           f"devices")

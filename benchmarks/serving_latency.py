@@ -27,6 +27,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import timed, write_json
 
 
@@ -153,6 +155,7 @@ def run(n_requests: int = 256, hidden: int = 128, rate_mult: float = 24.0,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print(f"loop   : {res['loop_pred_per_s']:8.2f} pred/s  (sequential "
           f"predict_graph, {res['n_requests']} requests)")

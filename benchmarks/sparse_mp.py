@@ -32,6 +32,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import timed, write_json
 
 VARIANTS = ("graphsage", "gcn", "gat", "gin", "mlp")
@@ -153,6 +155,7 @@ def run(n_graphs: int = 96, hidden: int = 64, repeats: int = 3):
 
 
 def main():
+    enable_compile_cache()
     res = run()
     gat, sage = res["gat"], res["graphsage"]
     print(f"gat    : dense {gat['dense_pred_per_s']:8.2f}/s  sparse "

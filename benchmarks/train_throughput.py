@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import write_json
 
 
@@ -80,6 +82,7 @@ def run(n_samples: int = 512, hidden: int = 16, batch_size: int = 4,
 
 
 def main():
+    enable_compile_cache()
     res = run()
     print(f"eager : {res['eager_steps_per_s']:9.2f} steps/s")
     print(f"scan  : {res['scan_steps_per_s']:9.2f} steps/s")

@@ -1,19 +1,10 @@
-"""Cross-version JAX compatibility helpers."""
+"""JAX API helpers shared across the repo."""
 from __future__ import annotations
 
 import jax
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``. The flag
-    means the same thing (skip replication checking) in both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check_vma)
+    """``jax.shard_map``; ``check_vma=False`` skips replication checking."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -24,7 +24,7 @@ compilation across the whole sweep and fill the batch dimension.
 With a ``PMGNSConfig(layout="packed")`` model, steps 1–3 are replaced by
 the **packed hot path**: a greedy token-budget bin-packer
 (:func:`~repro.core.batching.pack_graphs`) mixes graphs of different
-sizes onto one flat node axis, each bin ships as two donated staging
+sizes onto one flat node axis, each bin ships as two flat staging
 buffers, and the compile cache is keyed by ``(P, Q, G)`` budget rung —
 a handful of shapes for any traffic mix instead of the bucket
 cross-product (see ``benchmarks/packed_batching.py``).
@@ -47,8 +47,9 @@ from .batching import (DEFAULT_BUCKETS, DEFAULT_NODE_BUDGET, GraphSample,
                        next_pow2, pack_edges, pack_graphs, packed_rung,
                        packed_rung_ladder, packed_shape,
                        resolve_packed_budgets, sample_from_graph)
-from .gnn import (PMGNSConfig, make_infer_fn, make_staged_packed_infer_fn,
-                  packed_staging_layout)
+from ..kernels import ops as kernel_ops
+from .gnn import (PMGNSConfig, fused_kernel_plan, make_infer_fn,
+                  make_staged_packed_infer_fn, packed_staging_layout)
 from .ir import OpGraph
 from .static_features import STATIC_FEATURE_DIM, STATIC_FEATURE_DIM_EXT
 
@@ -141,6 +142,16 @@ class EngineStats:
     #: Max |bf16 − f32| prediction delta measured on a synthetic packed
     #: batch at warmup (``None`` until a bf16 packed engine warms up).
     bf16_max_abs_delta: Optional[float] = None
+    #: What the model's kernel dispatchers run: ``"pallas"`` when the
+    #: model asks for kernels (``use_pallas``) on a TPU backend, else
+    #: ``"ref"`` (the lax twins; ``repro.kernels.ops``).
+    kernel_impl: str = "ref"
+    #: Fused message-passing layers, summed over the compiled packed
+    #: shapes, that run the Pallas kernel / that the dispatcher sent to
+    #: the lax reference because their VMEM state does not fit
+    #: (``repro.core.gnn.fused_kernel_plan``).
+    fused_kernel_layers: int = 0
+    fused_fallback_layers: int = 0
 
     @property
     def padding_waste_frac(self) -> float:
@@ -206,6 +217,9 @@ class PredictionEngine:
         #: staged cast point and always run f32.
         self._precision = cfg.resolved_precision
         self.stats.precision = self._precision
+        self.stats.kernel_impl = ("pallas" if cfg.use_pallas
+                                  and kernel_ops.kernel_impl() == "pallas"
+                                  else "ref")
         if self._precision == "bf16" and cfg.resolved_layout == "packed":
             import ml_dtypes
             self._stage_dtype = ml_dtypes.bfloat16
@@ -229,7 +243,7 @@ class PredictionEngine:
         # (node_bucket[, edge_bucket], batch_bucket) — or packed
         # (P, Q, G) budget — shapes have compiled, for stats. Packed
         # shapes get a staged-buffer closure each (two flat host→device
-        # transfers per chunk, donated on accelerators).
+        # transfers per chunk).
         self._infer = make_infer_fn(cfg)
         self._staged: dict = {}
         self._compiled_shapes: set = set()
@@ -262,6 +276,9 @@ class PredictionEngine:
             if key not in self._staged:
                 self._staged[key] = make_staged_packed_infer_fn(
                     self.cfg, p, q, g)
+                kern, fallback = fused_kernel_plan(self.cfg, p)
+                self.stats.fused_kernel_layers += kern
+                self.stats.fused_fallback_layers += fallback
             return self._staged[key]
 
     def warmup(self, node_buckets: Optional[Sequence[int]] = None,
@@ -466,9 +483,7 @@ class PredictionEngine:
         The bin flattens onto a rung of the engine's ``(P, Q, G)``
         budget ladder (:func:`~repro.core.batching.packed_shape`); an
         oversize lone graph escalates its shape. The chunk ships as two
-        flat staging buffers which the jitted apply slices and — on
-        accelerator backends — takes by donation, so chunk arrays and
-        model activations share device memory.
+        flat staging buffers which the jitted apply slices.
         """
         nb, eb, gb = self._budgets
         p, q, g = packed_shape(chunk, nb, eb, gb)

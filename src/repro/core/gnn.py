@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -490,6 +489,28 @@ def _fused_mp_stack(p: Params, cfg: PMGNSConfig, x, mask, edges, edge_mask):
     return h
 
 
+def fused_kernel_plan(cfg: PMGNSConfig, p: int) -> Tuple[int, int]:
+    """``(kernel, fallback)`` fused layers for a packed bin of ``p`` nodes.
+
+    Mirrors the dispatch of :func:`_fused_mp_stack`: how many message-
+    passing layers run the fused Pallas kernel, and how many the
+    dispatcher sends to the lax reference because their resident state
+    does not fit VMEM (:func:`repro.kernels.ops.fused_fits`). ``(0, 0)``
+    when inference does not dispatch to Pallas at all (``use_pallas``
+    off, no fused stack, the MLP baseline, or a non-TPU backend).
+    """
+    from ..kernels.ops import fused_fits, kernel_impl
+    if (not cfg.use_pallas or not cfg.resolved_fused or cfg.variant == "mlp"
+            or kernel_impl() != "pallas"):
+        return 0, 0
+    mode = "mean" if cfg.variant == "graphsage" else "sum"
+    # GAT's fused stage aggregates the [P, hidden] projection
+    f_in = cfg.hidden if cfg.variant == "gat" else cfg.node_feat_dim
+    dims = [f_in] + [cfg.hidden] * (cfg.n_gnn_blocks - 1)
+    fits = sum(fused_fits(p, f, cfg.hidden, mode) for f in dims)
+    return fits, len(dims) - fits
+
+
 def pmgns_apply(p: Params, cfg: PMGNSConfig, batch: Dict[str, jnp.ndarray],
                 *, train: bool = False,
                 rng: Optional[jax.Array] = None) -> jnp.ndarray:
@@ -620,8 +641,7 @@ def packed_staging_layout(cfg: PMGNSConfig, p: int, q: int,
     return o1, o2, o3, o3 + g * cfg.static_dim, 2 * q + p
 
 
-def make_staged_packed_infer_fn(cfg: PMGNSConfig, p: int, q: int, g: int,
-                                donate: Optional[bool] = None):
+def make_staged_packed_infer_fn(cfg: PMGNSConfig, p: int, q: int, g: int):
     """Jitted packed infer over two flat staging buffers (one shape).
 
     The packed serving hot path (direct dict-based packed inference goes
@@ -632,13 +652,11 @@ def make_staged_packed_infer_fn(cfg: PMGNSConfig, p: int, q: int, g: int,
     transfers instead of six — on small serving requests the per-array
     dispatch overhead dominates the transfer time. The jitted function
     slices the buffers back into the packed batch dict (free at trace
-    time — all offsets are static for the fixed ``(P, Q, G)`` shape) and
-    both buffers are donated on accelerator backends, so staging memory
-    is recycled into activations. Returns ``(params, fbuf, ibuf) →
+    time — all offsets are static for the fixed ``(P, Q, G)`` shape).
+    The buffers are not donated: no output can alias them, so XLA would
+    refuse the donation. Returns ``(params, fbuf, ibuf) →
     [G, n_targets]`` physical-unit predictions.
     """
-    if donate is None:
-        donate = jax.default_backend() not in ("cpu",)
     feat, sdim = cfg.node_feat_dim, cfg.static_dim
     o1, o2, o3, _, _ = packed_staging_layout(cfg, p, q, g)
     # bf16 policy: the engine stages fbuf and holds params in bfloat16
@@ -646,7 +664,7 @@ def make_staged_packed_infer_fn(cfg: PMGNSConfig, p: int, q: int, g: int,
     # here, inside the jitted function, so drift is storage rounding only
     cast = cfg.resolved_precision != "f32"
 
-    @partial(jax.jit, donate_argnums=(1, 2) if donate else ())
+    @jax.jit
     def infer(params: Params, fbuf: jnp.ndarray,
               ibuf: jnp.ndarray) -> jnp.ndarray:
         if cast:

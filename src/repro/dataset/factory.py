@@ -365,6 +365,18 @@ def build_shard(plan: FactoryPlan, shard_index: int,
     return sidecar
 
 
+def _cpu_worker_init() -> None:
+    """Pin a shard worker to the CPU before it touches JAX.
+
+    Tracing is abstract and needs no accelerator, and a TPU belongs to
+    one process at a time: a parent that holds the chip can fan out
+    shard builds only if no worker tries to open it.
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _build_shard_job(out_dir: str, shard_index: int) -> Dict[str, Any]:
     """Worker entry point: re-reads the committed plan (single source of
     truth) so only ``(out_dir, shard_index)`` crosses the process
@@ -494,7 +506,8 @@ def build(out_dir: str, cfg: Optional[FactoryConfig] = None, *,
         from concurrent.futures import ProcessPoolExecutor
         ctx = mp.get_context("spawn")
         nw = min(workers, len(pending))
-        with ProcessPoolExecutor(max_workers=nw, mp_context=ctx) as pool:
+        with ProcessPoolExecutor(max_workers=nw, mp_context=ctx,
+                                 initializer=_cpu_worker_init) as pool:
             for sc in pool.map(_build_shard_job,
                                [out_dir] * len(pending), pending):
                 sidecars[sc["shard_index"]] = sc

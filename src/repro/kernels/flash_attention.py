@@ -94,7 +94,7 @@ def flash_attention_pallas(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = False, scale: float | None = None, window: int = 0,
     bq: int = 128, bk: int = 128, q_offset: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Attention over [B, H, S, D] tensors.
 

@@ -1,39 +1,39 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""Dispatchers from the model code to the Pallas kernels or their lax twins.
 
-Dispatch policy: the Pallas path is the TPU target; on CPU (this container)
-kernels execute in ``interpret=True`` mode for correctness validation, and
-callers can force the pure-jnp reference with ``impl="ref"`` (the default
-for CPU-bound training utilities, since interpret mode is slow).
+Policy: :func:`kernel_impl` is ``"pallas"`` on a TPU backend and
+``"ref"`` (the ``jnp``/``lax`` twins in :mod:`repro.kernels.ref`)
+everywhere else — the Pallas TPU kernels do not lower for a CPU. A caller
+may pass ``impl="pallas"`` or ``impl="ref"`` explicitly; ``"pallas"`` off
+a TPU runs the kernel in Pallas interpret mode, which is how the tests
+check the kernels on a CPU (they steer :func:`kernel_impl` with
+``monkeypatch`` to force it model-wide).
 
-The environment variable ``REPRO_KERNEL_IMPL`` overrides the default for
-the whole process (values: ``pallas`` | ``ref``).
+The fused message-passing kernels keep a whole ``[P, F]`` block resident
+in VMEM. A shape whose estimated working set does not fit the kernel's
+scoped VMEM limit (:func:`fused_fits`) runs the reference composition
+instead, and says so: the dispatcher warns at trace time, and the
+prediction engine counts such layers per compiled shape
+(``EngineStats.fused_fallback_layers``).
 """
 from __future__ import annotations
 
-import os
+import warnings
 from typing import Optional
 
 import jax
 
 from . import ref as _ref
 from .flash_attention import flash_attention_pallas
-from .sage_spmm import dense_aggregate_pallas, sage_aggregate_pallas
-from .segment_spmm import (edge_softmax_pallas, fused_gat_aggregate_pallas,
-                           fused_mp_layer_pallas, segment_aggregate_pallas,
+from .sage_spmm import dense_aggregate_pallas
+from .segment_spmm import (VMEM_LIMIT_BYTES, edge_softmax_pallas,
+                           fused_gat_aggregate_pallas, fused_mp_layer_pallas,
+                           fused_vmem_bytes, segment_aggregate_pallas,
                            segment_readout_pallas, segment_scatter_pallas)
 from .ssd_scan import ssd_scan_pallas
 
-# the fused megakernel keeps a whole-[P, F] accumulator (plus a degree
-# accumulator for mean mode) resident in VMEM; past this budget fall back
-# to the reference composition rather than thrash
-_FUSED_VMEM_BUDGET = 10 * 2**20
 
-
-def _default_impl() -> str:
-    env = os.environ.get("REPRO_KERNEL_IMPL")
-    if env in ("pallas", "ref"):
-        return env
-    # pallas-on-TPU, ref elsewhere (interpret mode is for tests)
+def kernel_impl() -> str:
+    """The default implementation: ``"pallas"`` on a TPU, else ``"ref"``."""
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
@@ -55,7 +55,7 @@ def dense_aggregate(adj: jax.Array, h: jax.Array, *, mode: str = "mean",
     (``mean``), GIN (``sum``), GCN (``sum`` over the pre-normalized
     adjacency).
     """
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return dense_aggregate_pallas(adj, h, mode=mode,
                                       interpret=_interpret())
@@ -73,7 +73,7 @@ def segment_aggregate(edges: jax.Array, edge_mask: jax.Array, h: jax.Array,
     a differentiable ``jnp.take``/``segment_sum`` pipeline; ``pallas``
     is the tiled one-hot-matmul kernel.
     """
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return segment_aggregate_pallas(edges, edge_mask, h, mode=mode,
                                         interpret=_interpret())
@@ -83,7 +83,7 @@ def segment_aggregate(edges: jax.Array, edge_mask: jax.Array, h: jax.Array,
 def segment_scatter(dst: jax.Array, edge_mask: jax.Array, msgs: jax.Array,
                     n_nodes: int, impl: Optional[str] = None) -> jax.Array:
     """Scatter per-edge messages into per-node sums — see ``segment_spmm``."""
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return segment_scatter_pallas(dst, edge_mask, msgs, n_nodes,
                                       interpret=_interpret())
@@ -100,7 +100,7 @@ def segment_readout(h: jax.Array, graph_ids: jax.Array,
     axis + ``graph_ids [P]`` → per-graph ``[G, F]`` (mean) or
     ``[G, 2F]`` (mean ⊕ max), replacing per-graph masked pooling.
     """
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return segment_readout_pallas(h, graph_ids, node_mask, n_graphs,
                                       kind=kind, interpret=_interpret())
@@ -115,21 +115,29 @@ def edge_softmax(scores: jax.Array, dst: jax.Array, edge_mask: jax.Array,
     GAT attention without the dense ``[B, N, N, heads]`` tensor; NaN-safe
     for destinations whose whole neighborhood is masked out.
     """
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return edge_softmax_pallas(scores, dst, edge_mask, n_nodes,
                                    interpret=_interpret())
     return _ref.edge_softmax_ref(scores, dst, edge_mask, n_nodes)
 
 
-def _fused_fits(p: int, f: int, h: int, mode: str) -> bool:
-    """True if the fused megakernel's resident state fits the VMEM budget."""
-    pp = p + ((-p) % 128)
-    acc = pp * f * 4
-    deg = pp * 128 * 4 if mode == "mean" else 0
-    x = pp * f * 4
-    weights = 2 * f * h * 4
-    return acc + deg + x + weights <= _FUSED_VMEM_BUDGET
+def fused_fits(p: int, f: int, h: int, mode: str) -> bool:
+    """True if a fused layer of this shape fits the kernel's VMEM limit."""
+    return fused_vmem_bytes(p, f, h, mode=mode) <= VMEM_LIMIT_BYTES
+
+
+def _fused_impl(impl: Optional[str], p: int, f: int, h: int,
+                mode: str, name: str) -> str:
+    impl = impl or kernel_impl()
+    if impl == "pallas" and not fused_fits(p, f, h, mode):
+        warnings.warn(
+            f"{name}: P={p}, F={f}, H={h} needs "
+            f"{fused_vmem_bytes(p, f, h, mode=mode) / 2**20:.0f} MiB of "
+            f"VMEM, over the {VMEM_LIMIT_BYTES / 2**20:.0f} MiB limit — "
+            f"running the lax reference", stacklevel=3)
+        return "ref"
+    return impl
 
 
 def fused_mp_layer(x: jax.Array, edges: jax.Array, edge_mask: jax.Array,
@@ -144,12 +152,12 @@ def fused_mp_layer(x: jax.Array, edges: jax.Array, edge_mask: jax.Array,
 
     gather → edge-mask → scatter(+mean) → self/neighbor combine → bias →
     activation → node-mask in a single kernel — see ``segment_spmm``.
-    Falls back to the reference composition when the whole-``[P, F]``
-    VMEM accumulator would blow the budget.
+    Runs the reference composition, with a warning, when the kernel's
+    resident state would not fit VMEM (:func:`fused_fits`).
     """
-    impl = impl or _default_impl()
-    if impl == "pallas" and _fused_fits(x.shape[0], x.shape[1],
-                                        w_neigh.shape[1], mode):
+    impl = _fused_impl(impl, x.shape[0], x.shape[1], w_neigh.shape[1], mode,
+                       "fused_mp_layer")
+    if impl == "pallas":
         return fused_mp_layer_pallas(
             x, edges, edge_mask, node_mask, w_neigh=w_neigh, w_self=w_self,
             bias=bias, mode=mode, combine=combine, self_scale=self_scale,
@@ -165,9 +173,9 @@ def fused_gat_aggregate(z: jax.Array, edges: jax.Array,
                         node_mask: jax.Array,
                         impl: Optional[str] = None) -> jax.Array:
     """Fused GAT post-softmax gather⊙attention→scatter — see ``segment_spmm``."""
-    impl = impl or _default_impl()
-    if impl == "pallas" and _fused_fits(z.shape[0], z.shape[1],
-                                        z.shape[1], "sum"):
+    impl = _fused_impl(impl, z.shape[0], z.shape[1], z.shape[1], "sum",
+                       "fused_gat_aggregate")
+    if impl == "pallas":
         return fused_gat_aggregate_pallas(z, edges, edge_mask, att,
                                           node_mask, interpret=_interpret())
     return _ref.fused_gat_aggregate_ref(z, edges, edge_mask, att, node_mask)
@@ -177,7 +185,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None, window: int = 0,
                     q_offset: int = 0, impl: Optional[str] = None):
     """Streaming-softmax attention — see ``flash_attention``."""
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return flash_attention_pallas(
             q, k, v, causal=causal, scale=scale, window=window,
@@ -189,7 +197,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128,
              impl: Optional[str] = None):
     """Chunked Mamba2 SSD scan — see ``ssd_scan``."""
-    impl = impl or _default_impl()
+    impl = impl or kernel_impl()
     if impl == "pallas":
         return ssd_scan_pallas(x, dt, A, B, C, chunk=chunk,
                                interpret=_interpret())

@@ -41,7 +41,7 @@ def _sage_kernel(adj_ref, h_ref, o_ref, *, mean: bool):
 @functools.partial(jax.jit, static_argnames=("mode", "bn", "bf", "interpret"))
 def dense_aggregate_pallas(adj: jax.Array, h: jax.Array, *,
                            mode: str = "mean", bn: int = 128,
-                           bf: int = 128, interpret: bool = True) -> jax.Array:
+                           bf: int = 128, interpret: bool = False) -> jax.Array:
     """agg_{j∈N(i)} h_j (``mean`` | ``sum``) for batched dense graphs.
 
     adj: [B, N, N] with adj[b, dst, src] ∈ {0,1};  h: [B, N, F].
@@ -79,7 +79,7 @@ def dense_aggregate_pallas(adj: jax.Array, h: jax.Array, *,
 
 
 def sage_aggregate_pallas(adj: jax.Array, h: jax.Array, *, bn: int = 128,
-                          bf: int = 128, interpret: bool = True) -> jax.Array:
+                          bf: int = 128, interpret: bool = False) -> jax.Array:
     """mean_{j∈N(i)} h_j — the original GraphSAGE entry point."""
     return dense_aggregate_pallas(adj, h, mode="mean", bn=bn, bf=bf,
                                   interpret=interpret)

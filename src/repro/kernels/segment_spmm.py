@@ -36,7 +36,11 @@ out as exact zeros (masked-denominator guard), never NaN.
 
 VMEM at the default tiles (bn=be=128, N≤1024, F≤512): h block
 ``N·F·4 ≤ 2 MB``, one-hots ≤ 128 KB, accumulators ≤ 256 KB — comfortably
-under the ~16 MB budget, with every matmul dimension a multiple of 128.
+under the compiler's default 16 MiB scoped limit, with every matmul
+dimension a multiple of 128. The fused packed-layout kernels keep a whole
+``[P, F]`` feature block and accumulator resident instead; they raise the
+scoped limit to :data:`VMEM_LIMIT_BYTES` and single-buffer their resident
+blocks (:func:`fused_vmem_bytes` is their working-set estimate).
 """
 from __future__ import annotations
 
@@ -48,6 +52,38 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _DEG_LANES = 128   # degree accumulator lane width (TPU min lane tile)
+
+#: Scoped VMEM the resident-state kernels may claim. A TPU v5e core has
+#: 128 MiB of VMEM; at the compiler's default 16 MiB the fused layer is
+#: refused at the top packed rung (P=4096, F=H=512). 100 MiB compiles
+#: P=4096 and P=8192 at that width for a described v5e chip and leaves
+#: headroom for Mosaic's internal scratch.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+_RESIDENT_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _resident(shape):
+    """BlockSpec for an operand that every grid step reads whole: one
+    buffer suffices, since its block index never changes."""
+    return pl.BlockSpec(shape, lambda t: (0,) * len(shape),
+                        pipeline_mode=pl.Buffered(1))
+
+
+def fused_vmem_bytes(p: int, f: int, h: int, *, mode: str = "sum",
+                     bn: int = 128, be: int = 128) -> int:
+    """Estimated VMEM working set of :func:`fused_mp_layer_pallas` (and,
+    with ``h = f``, of :func:`fused_gat_aggregate_pallas`) in bytes.
+
+    Resident feature block + f32 accumulator (+ degree accumulator for
+    ``mode="mean"``), both weight matrices, and the edge phase's
+    ``[be, P]``/``[P, be]`` one-hot and iota temporaries.
+    """
+    pp = p + ((-p) % bn)
+    resident = 2 * pp * f * 4 + 2 * f * h * 4 + 16 * pp * 4
+    deg = 2 * pp * _DEG_LANES * 4 if mode == "mean" else 0
+    temps = 4 * be * pp * 4 + be * f * 4 + 2 * bn * h * 4
+    return resident + deg + temps
 
 
 def _seg_gather_kernel(src_ref, h_ref, o_ref, *, n_pad: int):
@@ -125,7 +161,7 @@ def _scatter_with_degree(dst, em, msgs, n_nodes, bn, be, interpret):
 def segment_aggregate_pallas(edges: jax.Array, edge_mask: jax.Array,
                              h: jax.Array, *, mode: str = "mean",
                              bn: int = 128, be: int = 128,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool = False) -> jax.Array:
     """Sparse neighborhood aggregation ``agg_{e: dst_e=i} h[src_e]``.
 
     edges: [B, E, 2] int32 (src, dst); edge_mask: [B, E]; h: [B, N, F].
@@ -177,7 +213,7 @@ def segment_aggregate_pallas(edges: jax.Array, edge_mask: jax.Array,
 def segment_scatter_pallas(dst: jax.Array, edge_mask: jax.Array,
                            msgs: jax.Array, n_nodes: int, *,
                            bn: int = 128, be: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Scatter per-edge messages ``[B, E, F]`` into ``[B, N, F]`` sums.
 
     The scatter half of :func:`segment_aggregate_pallas`, for callers
@@ -200,25 +236,28 @@ def segment_scatter_pallas(dst: jax.Array, edge_mask: jax.Array,
     return out
 
 
-def _seg_readout_kernel(gid_ref, w_ref, h_ref, sum_ref, cnt_ref, max_ref, *,
-                        bg: int):
+def _seg_readout_kernel(gid_ref, w_ref, gidc_ref, wc_ref, h_ref, sum_ref,
+                        cnt_ref, max_ref, *, bg: int):
     """Fused per-graph (sum, count, max) over one node tile.
 
     Runs per (graph-tile, node-tile) with the node axis innermost: the
     output blocks are revisited across node tiles and accumulated. The
     one-hot selection matmul is the MXU-native gather (see module
-    docstring); max is a masked broadcast-max on the VPU.
+    docstring) and the count is the same one-hot against a ones block.
+    Max is a masked max on the VPU, one graph of the tile at a time:
+    graph ids and the mask also arrive as ``[bp, 1]`` columns so the
+    selection broadcasts along lanes — Mosaic cannot lay out a
+    ``[bg, bp, F]`` select or move a lane vector onto sublanes.
     """
     k = pl.program_id(1)
+    g0 = pl.program_id(0) * bg
     gid = gid_ref[0]                                    # [bp] int32
     w = w_ref[0]                                        # [bp]
     h = h_ref[0]                                        # [bp, F]
     bp = gid.shape[0]
     neg = jnp.finfo(h.dtype).min
-    rows = pl.program_id(0) * bg + jax.lax.broadcasted_iota(
-        jnp.int32, (bg, bp), 0)
-    sel = (gid[None, :] == rows) & (w[None, :] > 0)     # [bg, bp] bool
-    oh = sel.astype(h.dtype)
+    rows = g0 + jax.lax.broadcasted_iota(jnp.int32, (bg, bp), 0)
+    oh = ((gid[None, :] == rows) & (w[None, :] > 0)).astype(h.dtype)
 
     @pl.when(k == 0)
     def _init():
@@ -229,11 +268,15 @@ def _seg_readout_kernel(gid_ref, w_ref, h_ref, sum_ref, cnt_ref, max_ref, *,
     sum_ref[0] += jnp.dot(oh, h,
                           preferred_element_type=jnp.float32
                           ).astype(sum_ref.dtype)
-    cnt = jnp.sum(oh, axis=1)                           # [bg]
-    cnt_ref[0] += jnp.broadcast_to(cnt[:, None],
-                                   (bg, _DEG_LANES)).astype(cnt_ref.dtype)
-    hb = jnp.where(sel[:, :, None], h[None, :, :], neg)  # [bg, bp, F]
-    max_ref[0] = jnp.maximum(max_ref[0], jnp.max(hb, axis=1))
+    cnt_ref[0] += jnp.dot(oh, jnp.ones((bp, _DEG_LANES), h.dtype),
+                          preferred_element_type=jnp.float32
+                          ).astype(cnt_ref.dtype)
+    live = wc_ref[...] > 0                              # [bp, 1]
+    gid_c = gidc_ref[...]                               # [bp, 1]
+    for j in range(bg):
+        sel = live & (gid_c == g0 + j)                  # [bp, 1]
+        m = jnp.max(jnp.where(sel, h, neg), axis=0, keepdims=True)
+        max_ref[0, j:j + 1, :] = jnp.maximum(max_ref[0, j:j + 1, :], m)
 
 
 @functools.partial(jax.jit, static_argnames=("n_graphs", "kind", "bg", "bp",
@@ -242,7 +285,7 @@ def segment_readout_pallas(h: jax.Array, graph_ids: jax.Array,
                            node_mask: jax.Array, n_graphs: int, *,
                            kind: str = "mean_max", bg: int = 8,
                            bp: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Fused segment-mean/max graph readout over a packed flat node axis.
 
     h: [P, F]; graph_ids: [P] int32; node_mask: [P]. One pass computes
@@ -274,6 +317,8 @@ def segment_readout_pallas(h: jax.Array, graph_ids: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bp), lambda i, k: (0, k)),
             pl.BlockSpec((1, bp), lambda i, k: (0, k)),
+            pl.BlockSpec((bp, 1), lambda i, k: (k, 0)),
+            pl.BlockSpec((bp, 1), lambda i, k: (k, 0)),
             pl.BlockSpec((1, bp, F), lambda i, k: (0, k, 0)),
         ],
         out_specs=[
@@ -287,7 +332,7 @@ def segment_readout_pallas(h: jax.Array, graph_ids: jax.Array,
             jax.ShapeDtypeStruct((1, Gp, F), h.dtype),
         ],
         interpret=interpret,
-    )(gid[None], w[None], h[None])
+    )(gid[None], w[None], gid[:, None], w[:, None], h[None])
     sums, cnt, mx = sums[0, :n_graphs], cnt[0, :n_graphs, :1], mx[0, :n_graphs]
     mean = sums / jnp.maximum(cnt, 1.0)
     if kind == "mean":
@@ -376,7 +421,7 @@ def fused_mp_layer_pallas(x: jax.Array, edges: jax.Array,
                           mode: str = "mean", combine: str = "split",
                           self_scale: jax.Array | None = None,
                           act: str = "relu", bn: int = 128, be: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool = False) -> jax.Array:
     """Fused message-passing megakernel over the packed flat node axis.
 
     One ``pallas_call`` covers gather → edge-mask → scatter-accumulate
@@ -435,17 +480,18 @@ def fused_mp_layer_pallas(x: jax.Array, edges: jax.Array,
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
-            pl.BlockSpec((1, Pp), lambda t: (0, 0)),
-            pl.BlockSpec((1, Pp), lambda t: (0, 0)),
-            pl.BlockSpec((Pp, F), lambda t: (0, 0)),
-            pl.BlockSpec((F, H), lambda t: (0, 0)),
-            pl.BlockSpec((F, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
+            _resident((1, Pp)),
+            _resident((1, Pp)),
+            _resident((Pp, F)),
+            _resident((F, H)),
+            _resident((F, H)),
+            _resident((1, H)),
         ],
         out_specs=pl.BlockSpec((bn, H),
                                lambda t: (jnp.maximum(t - ke, 0), 0)),
         out_shape=jax.ShapeDtypeStruct((Pp, H), x.dtype),
         scratch_shapes=scratch,
+        compiler_params=_RESIDENT_PARAMS,
         interpret=interpret,
     )(src[None], dst[None], em[None], nm[None], ss[None], x, w_neigh, ws,
       b[None])
@@ -504,7 +550,7 @@ def fused_gat_aggregate_pallas(z: jax.Array, edges: jax.Array,
                                edge_mask: jax.Array, att: jax.Array,
                                node_mask: jax.Array, *, bn: int = 128,
                                be: int = 128,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool = False) -> jax.Array:
     """Fused GAT post-softmax stage over the packed flat node axis.
 
     z: [P, D] projected features (heads concatenated, D = H·dh);
@@ -542,14 +588,15 @@ def fused_gat_aggregate_pallas(z: jax.Array, edges: jax.Array,
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
             pl.BlockSpec((1, be), lambda t: (0, jnp.minimum(t, ke - 1))),
-            pl.BlockSpec((1, Pp), lambda t: (0, 0)),
-            pl.BlockSpec((Pp, D), lambda t: (0, 0)),
+            _resident((1, Pp)),
+            _resident((Pp, D)),
             pl.BlockSpec((be, Hp), lambda t: (jnp.minimum(t, ke - 1), 0)),
         ],
         out_specs=pl.BlockSpec((bn, D),
                                lambda t: (jnp.maximum(t - ke, 0), 0)),
         out_shape=jax.ShapeDtypeStruct((Pp, D), z.dtype),
         scratch_shapes=[pltpu.VMEM((Pp, D), jnp.float32)],
+        compiler_params=_RESIDENT_PARAMS,
         interpret=interpret,
     )(src[None], dst[None], em[None], nm[None], z, a)
     return out[:P].astype(z.dtype)
@@ -622,7 +669,7 @@ def _softmax_norm_kernel(s_ref, dst_ref, em_ref, m_ref, d_ref, a_ref, *,
 def edge_softmax_pallas(scores: jax.Array, dst: jax.Array,
                         edge_mask: jax.Array, n_nodes: int, *,
                         bn: int = 128, be: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """Per-destination softmax over incoming edges (GAT attention).
 
     scores: [B, E, H]; dst: [B, E] int32; edge_mask: [B, E].

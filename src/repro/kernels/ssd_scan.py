@@ -80,7 +80,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
                     B: jax.Array, C: jax.Array, *, chunk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Chunked SSD over [Bt, S, H, P] inputs.
 
     x:  [Bt, S, H, P]   dt: [Bt, S, H]   A: [H]

@@ -68,6 +68,8 @@ class Hardware:
     hbm_bytes: float
 
 
+#: One TPU v5e chip. Source: Google Cloud documentation, "TPU v5e" —
+#: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s; ``link_bw`` is one ICI link.
 V5E = Hardware("tpu-v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
                hbm_bytes=16e9)
 
@@ -80,10 +82,27 @@ CPU_HOST = Hardware("cpu-host-nominal", peak_flops=2.0e11, hbm_bw=5.0e10,
                     link_bw=1.0e9, hbm_bytes=8e9)
 
 
-def default_hardware() -> Hardware:
-    """The roofline envelope for the current jax backend."""
+#: Published peaks by ``jax.Device.device_kind``. A kind that is not
+#: here has no envelope: :func:`default_hardware` raises rather than
+#: guess. The CPU has none either — CPU callers that want the nominal
+#: :data:`CPU_HOST` envelope name it.
+PEAKS_BY_DEVICE_KIND: Dict[str, Hardware] = {
+    "TPU v5 lite": V5E,        # the kind JAX reports for a v5e chip
+}
+
+
+def default_hardware(device=None) -> Hardware:
+    """The roofline envelope of ``device`` (default: the first device),
+    looked up by its ``device_kind`` in :data:`PEAKS_BY_DEVICE_KIND`."""
     import jax
-    return V5E if jax.default_backend() == "tpu" else CPU_HOST
+    kind = (device or jax.devices()[0]).device_kind
+    try:
+        return PEAKS_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {kind!r} (known: "
+            f"{sorted(PEAKS_BY_DEVICE_KIND)}); pass an explicit Hardware, "
+            f"e.g. CPU_HOST for the nominal CPU envelope") from None
 
 
 @dataclasses.dataclass
@@ -445,7 +464,7 @@ def dense_aggregate_traffic(b: int, n: int, f: int) -> Dict[str, float]:
 
 
 def achieved_rates(flops: float, byts: float, wall_s: float,
-                   hw: Optional[Hardware] = None) -> Dict[str, object]:
+                   hw: Hardware) -> Dict[str, object]:
     """Measured wall time + modeled (FLOPs, bytes) → achieved-rate row.
 
     ``pct_of_roofline`` is the fraction of the wall time explained by
@@ -455,7 +474,6 @@ def achieved_rates(flops: float, byts: float, wall_s: float,
     :data:`CPU_HOST` the absolute number is nominal (see its docstring);
     the fused-vs-unfused *ratio* is the machine-independent signal.
     """
-    hw = hw or default_hardware()
     wall = max(float(wall_s), 1e-12)
     compute_s = flops / hw.peak_flops
     memory_s = byts / hw.hbm_bw

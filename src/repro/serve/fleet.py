@@ -334,7 +334,8 @@ class ReplicaPool:
     def stats(self) -> EngineStats:
         """Aggregated :class:`EngineStats` across replicas (counters
         summed; padding waste derives from the summed slot counters;
-        precision policy is fleet-uniform so replica 0 speaks for it)."""
+        precision and kernel policy are fleet-uniform so replica 0
+        speaks for them)."""
         agg = EngineStats()
         deltas = []
         for r in self.replicas:
@@ -347,9 +348,12 @@ class ReplicaPool:
             agg.recompiles += s.recompiles
             agg.node_slots_total += s.node_slots_total
             agg.node_slots_real += s.node_slots_real
+            agg.fused_kernel_layers += s.fused_kernel_layers
+            agg.fused_fallback_layers += s.fused_fallback_layers
             if s.bf16_max_abs_delta is not None:
                 deltas.append(s.bf16_max_abs_delta)
         agg.precision = self.replicas[0].stats.precision
+        agg.kernel_impl = self.replicas[0].stats.kernel_impl
         agg.bf16_max_abs_delta = max(deltas) if deltas else None
         return agg
 

@@ -770,8 +770,13 @@ class PredictionService:
     def _infra_error(e: BaseException) -> bool:
         """Failures caused by the *service*, not the request content —
         they must never quarantine the bin's riders (re-running the same
-        graphs on a healthy fleet would succeed)."""
-        return isinstance(e, (NoHealthyReplicaError, DeadlineExceededError))
+        graphs on a healthy fleet would succeed). A JAX compile or
+        runtime error (a kernel the compiler refuses, a device fault,
+        device memory exhausted) is the backend's: bisecting would only
+        repeat it for every rider and condemn them all as poison."""
+        import jax
+        return isinstance(e, (NoHealthyReplicaError, DeadlineExceededError,
+                              jax.errors.JaxRuntimeError))
 
     def _run_bin_sync(self, chunk, deadline: Optional[float]):
         """One synchronous bin dispatch; the fleet backend also gets

@@ -7,6 +7,7 @@ into one call; ``fused_gat_aggregate_pallas`` does the GAT post-softmax
 stage. All interpret-mode, so the file runs fully on the CPU CI runner.
 """
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -16,9 +17,12 @@ import pytest
 from repro.core.batching import collate_packed
 from repro.core.gnn import PMGNSConfig, pmgns_infer, pmgns_init
 from repro.dataset.builder import synthetic_samples
-from repro.kernels import ops, ref
-from repro.kernels.segment_spmm import (fused_gat_aggregate_pallas,
-                                        fused_mp_layer_pallas)
+from repro.kernels import ops, ref, segment_spmm
+
+fused_gat_aggregate_pallas = partial(
+    segment_spmm.fused_gat_aggregate_pallas, interpret=True)
+fused_mp_layer_pallas = partial(segment_spmm.fused_mp_layer_pallas,
+                                interpret=True)
 
 RNG = np.random.default_rng(0)
 
@@ -124,17 +128,24 @@ def test_fused_gat_aggregate_matches_ref(p, q, h):
                                atol=1e-5, rtol=1e-5)
 
 
-def test_fused_dispatch_vmem_guard_falls_back_to_ref():
-    # a shape whose whole-[P, F] accumulator exceeds the VMEM budget
-    # must dispatch to the reference path even under impl="pallas"
-    assert not ops._fused_fits(200_000, 64, 64, "mean")
-    assert ops._fused_fits(4096, 64, 64, "mean")
+def test_fused_dispatch_vmem_guard_falls_back_to_ref(monkeypatch):
+    # a shape whose whole-[P, F] accumulator exceeds the VMEM limit
+    # must dispatch to the reference path even under impl="pallas" —
+    # and say so; every rung of the default ladder at paper width fits
+    assert not ops.fused_fits(200_000, 64, 64, "mean")
+    assert ops.fused_fits(4096, 512, 512, "mean")
     x, edges, emask, nmask = _packed_graph(64, 32)
     wn, ws, b = _weights(16, 8)
     out = ops.fused_mp_layer(x, edges, emask, nmask, w_neigh=wn, w_self=ws,
                              bias=b, impl="pallas")
     exp = ref.fused_mp_layer_ref(x, edges, emask, nmask, w_neigh=wn,
                                  w_self=ws, bias=b)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               atol=1e-5, rtol=1e-5)
+    monkeypatch.setattr(ops, "fused_fits", lambda *a: False)
+    with pytest.warns(UserWarning, match="lax reference"):
+        out = ops.fused_mp_layer(x, edges, emask, nmask, w_neigh=wn,
+                                 w_self=ws, bias=b, impl="pallas")
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                atol=1e-5, rtol=1e-5)
 
@@ -185,3 +196,26 @@ def test_fused_training_uses_composed_path():
     y_inf = pmgns_apply(params, cfg, batch, train=False)
     np.testing.assert_allclose(np.asarray(y_tr), np.asarray(y_inf),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_engine_counts_fused_dispatch(monkeypatch):
+    """Every compiled packed shape adds its fused layers to EngineStats,
+    split into kernel and fallback by the same VMEM guard the
+    dispatcher applies; off a TPU nothing dispatches to Pallas."""
+    from repro.core.engine import PredictionEngine
+    from repro.core.gnn import fused_kernel_plan
+    cfg = PMGNSConfig(hidden=512, layout="packed", use_pallas=True)
+    assert fused_kernel_plan(cfg, 4096) == (0, 0)
+    monkeypatch.setattr(ops, "kernel_impl", lambda: "pallas")
+    assert fused_kernel_plan(cfg, 4096) == (3, 0)
+    assert fused_kernel_plan(dataclasses.replace(cfg, use_pallas=False),
+                             4096) == (0, 0)
+    small = dataclasses.replace(cfg, hidden=16)
+    eng = PredictionEngine(pmgns_init(jax.random.PRNGKey(0), small), small)
+    assert eng.stats.kernel_impl == "pallas"
+    eng._packed_fn(256, 416, 16)
+    monkeypatch.setattr(ops, "fused_fits", lambda *a: False)
+    eng._packed_fn(512, 832, 32)
+    eng._packed_fn(512, 832, 32)                 # cached: not counted again
+    assert (eng.stats.fused_kernel_layers,
+            eng.stats.fused_fallback_layers) == (3, 3)

@@ -137,23 +137,16 @@ def test_sparse_mp_matches_dense(variant):
 
 
 @pytest.mark.parametrize("variant", ["graphsage", "gcn", "gat", "gin"])
-def test_sparse_pallas_matches_sparse_ref(variant):
+def test_sparse_pallas_matches_sparse_ref(variant, monkeypatch):
     cfg_ref = PMGNSConfig(variant=variant, hidden=32, sparse_mp=True)
     cfg_pal = PMGNSConfig(variant=variant, hidden=32, sparse_mp=True,
                           use_pallas=True)
     params = pmgns_init(jax.random.PRNGKey(1), cfg_ref)
     _, sparse = _paired_batches(seed=5)
     o1 = pmgns_apply(params, cfg_ref, sparse)
-    import os
-    prior = os.environ.get("REPRO_KERNEL_IMPL")
-    os.environ["REPRO_KERNEL_IMPL"] = "pallas"
-    try:
-        o2 = pmgns_apply(params, cfg_pal, sparse)
-    finally:
-        if prior is None:
-            del os.environ["REPRO_KERNEL_IMPL"]
-        else:
-            os.environ["REPRO_KERNEL_IMPL"] = prior
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "kernel_impl", lambda: "pallas")
+    o2 = pmgns_apply(params, cfg_pal, sparse)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                atol=1e-4, rtol=1e-4)
 
@@ -263,11 +256,11 @@ def test_packed_matches_dense_per_sample(variant):
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_packed_pallas_matches_packed_ref():
+def test_packed_pallas_matches_packed_ref(monkeypatch):
     """use_pallas routes the packed readout + segment layers through the
     kernels; numbers match the lax reference."""
-    import os
     from repro.core.batching import collate_packed
+    from repro.kernels import ops
     cfg_ref = PMGNSConfig(hidden=32, layout="packed")
     cfg_pal = PMGNSConfig(hidden=32, layout="packed", use_pallas=True)
     params = pmgns_init(jax.random.PRNGKey(1), cfg_ref)
@@ -275,15 +268,8 @@ def test_packed_pallas_matches_packed_ref():
          for k, v in collate_packed(_mixed_samples(seed=22)).items()
          if k not in ("y", "wt")}
     o1 = pmgns_apply(params, cfg_ref, b)
-    prior = os.environ.get("REPRO_KERNEL_IMPL")
-    os.environ["REPRO_KERNEL_IMPL"] = "pallas"
-    try:
-        o2 = pmgns_apply(params, cfg_pal, b)
-    finally:
-        if prior is None:
-            del os.environ["REPRO_KERNEL_IMPL"]
-        else:
-            os.environ["REPRO_KERNEL_IMPL"] = prior
+    monkeypatch.setattr(ops, "kernel_impl", lambda: "pallas")
+    o2 = pmgns_apply(params, cfg_pal, b)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                atol=1e-4, rtol=1e-4)
 

@@ -7,13 +7,18 @@ tile multiple, E one past it, every edge masked, a bin whose last graph
 slots hold zero real nodes, and a single graph at the exact node
 budget. All interpret-mode, so they run fully on the CPU CI runner.
 """
+from functools import partial
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.segment_spmm import (edge_softmax_pallas,
-                                        segment_readout_pallas)
+from repro.kernels import ref, segment_spmm
+
+edge_softmax_pallas = partial(segment_spmm.edge_softmax_pallas,
+                              interpret=True)
+segment_readout_pallas = partial(segment_spmm.segment_readout_pallas,
+                                 interpret=True)
 
 RNG = np.random.default_rng(0)
 

@@ -1,23 +1,34 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode).
 
-Every kernel here runs with ``interpret=True`` (the pallas_call default
-in this repo on non-TPU backends), so the whole file executes — not
-skips — on the CPU-only CI runner.
+Every kernel here runs with ``interpret=True`` — the entry points compile
+for the TPU by default — so the whole file executes, not skips, on a
+CPU-only runner.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.sage_spmm import (dense_aggregate_pallas,
-                                     sage_aggregate_pallas)
-from repro.kernels.segment_spmm import (edge_softmax_pallas,
-                                        segment_aggregate_pallas,
-                                        segment_readout_pallas,
-                                        segment_scatter_pallas)
-from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.kernels.flash_attention import flash_attention_pallas as _fa
+from repro.kernels.sage_spmm import dense_aggregate_pallas as _dense
+from repro.kernels.sage_spmm import sage_aggregate_pallas as _sage
+from repro.kernels.segment_spmm import edge_softmax_pallas as _softmax
+from repro.kernels.segment_spmm import segment_aggregate_pallas as _agg
+from repro.kernels.segment_spmm import segment_readout_pallas as _readout
+from repro.kernels.segment_spmm import segment_scatter_pallas as _scatter
+from repro.kernels.ssd_scan import ssd_scan_pallas as _ssd
+
+flash_attention_pallas = partial(_fa, interpret=True)
+dense_aggregate_pallas = partial(_dense, interpret=True)
+sage_aggregate_pallas = partial(_sage, interpret=True)
+edge_softmax_pallas = partial(_softmax, interpret=True)
+segment_aggregate_pallas = partial(_agg, interpret=True)
+segment_readout_pallas = partial(_readout, interpret=True)
+segment_scatter_pallas = partial(_scatter, interpret=True)
+ssd_scan_pallas = partial(_ssd, interpret=True)
 
 RNG = np.random.default_rng(0)
 

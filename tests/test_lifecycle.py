@@ -376,6 +376,30 @@ def test_infra_failure_does_not_quarantine(packed_dippm):
         pool.close()
 
 
+def test_jax_runtime_error_is_infrastructure(packed_dippm, monkeypatch):
+    """A compile error or device fault from JAX fails the riders with
+    that error as it is: no bisection, nobody condemned as poison."""
+    import jax
+    svc = packed_dippm.serve(max_wait_ms=30_000.0, max_batch_graphs=1024)
+
+    def broken(chunk):
+        raise jax.errors.JaxRuntimeError(
+            "INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(svc.engine, "run_bin", broken)
+    try:
+        futs = [svc.submit(_graph(7, seed=s)) for s in range(4)]
+        svc.flush()
+        errs = [f.exception(timeout=60) for f in futs]
+        assert all(isinstance(e, jax.errors.JaxRuntimeError) for e in errs)
+        st = svc.stats
+        assert st.failed == 4 and st.completed == 0
+        assert st.poisoned == 0 and st.bisect_runs == 0
+        assert st.quarantine_entries == 0
+    finally:
+        svc.close()
+
+
 # ---- circuit breakers in the fleet -----------------------------------------
 
 def test_breaker_probe_revives_replica_after_outage(packed_dippm):

@@ -80,8 +80,9 @@ def test_error_feedback_recovers_signal():
 
 
 def test_compressed_allreduce_single_device_mesh():
+    from repro.launch.mesh import make_mesh
     from repro.runtime.compression import compressed_grad_allreduce
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((4, 8)),
                           jnp.float32)}
     err = {"w": jnp.zeros((4, 8), jnp.float32)}

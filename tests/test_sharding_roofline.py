@@ -101,8 +101,9 @@ def test_shard_map_moe_on_single_device_mesh():
     production path)."""
     import dataclasses
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models.parallel import ParallelCtx
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_smoke_config("deepseek-v2-236b")
     ctx = ParallelCtx(mesh=mesh, data_axes=("data",), model_axis="model",
                       moe_impl="ep")
@@ -115,3 +116,13 @@ def test_shard_map_moe_on_single_device_mesh():
         loss, _ = jax.jit(lambda p, b: lm.loss_fn(p, cfg, b, ctx))(
             params, inputs)
     assert bool(jnp.isfinite(loss))
+
+
+def test_default_hardware_looks_up_device_kind():
+    """Peaks come from the device's kind; an unknown kind (the CPU
+    among them) raises instead of borrowing another device's envelope."""
+    from types import SimpleNamespace
+    from repro.roofline.analysis import V5E, default_hardware
+    assert default_hardware(SimpleNamespace(device_kind="TPU v5 lite")) is V5E
+    with pytest.raises(KeyError, match="CPU_HOST"):
+        default_hardware(jax.devices("cpu")[0])
