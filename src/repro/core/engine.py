@@ -51,6 +51,7 @@ from ..kernels import ops as kernel_ops
 from .gnn import (PMGNSConfig, fused_kernel_plan, make_infer_fn,
                   make_staged_packed_infer_fn, packed_staging_layout)
 from .ir import OpGraph
+from . import spans
 from .static_features import STATIC_FEATURE_DIM, STATIC_FEATURE_DIM_EXT
 
 
@@ -423,34 +424,54 @@ class PredictionEngine:
         bb = next_pow2(b)
         feat = chunk[0].x.shape[1]
         sdim = chunk[0].static.shape[0]
-        x = np.zeros((bb, node_bucket, feat), dtype=np.float32)
-        mask = np.zeros((bb, node_bucket), dtype=np.float32)
-        static = np.zeros((bb, sdim), dtype=np.float32)
-        for i, s in enumerate(chunk):
-            x[i], mask[i], static[i] = s.x, s.mask, s.static
-        batch = {"x": jnp.asarray(x), "mask": jnp.asarray(mask),
-                 "static": jnp.asarray(static)}
-        if self.sparse:
-            eb = max(edge_bucket_for(max(s.n_edges for s in chunk)),
-                     self._edge_floor(node_bucket))
-            edges = np.zeros((bb, eb, 2), dtype=np.int32)
-            emask = np.zeros((bb, eb), dtype=np.float32)
-            pack_edges(chunk, eb, edges_out=edges[:b], mask_out=emask[:b])
-            batch["edges"] = jnp.asarray(edges)
-            batch["edge_mask"] = jnp.asarray(emask)
-            fn = self._infer_fn(node_bucket, bb, eb)
-        else:
-            adj = np.zeros((bb, node_bucket, node_bucket), dtype=np.float32)
+        with spans.TraceAnnotation(spans.STAGE, graphs=b):
+            x = np.zeros((bb, node_bucket, feat), dtype=np.float32)
+            mask = np.zeros((bb, node_bucket), dtype=np.float32)
+            static = np.zeros((bb, sdim), dtype=np.float32)
             for i, s in enumerate(chunk):
-                dense_adj(s.edges, node_bucket, out=adj[i])
-            batch["adj"] = jnp.asarray(adj)
-            fn = self._infer_fn(node_bucket, bb)
-        out = np.asarray(fn(self.params, batch))
+                x[i], mask[i], static[i] = s.x, s.mask, s.static
+            batch = {"x": jnp.asarray(x), "mask": jnp.asarray(mask),
+                     "static": jnp.asarray(static)}
+            eb = None
+            if self.sparse:
+                eb = max(edge_bucket_for(max(s.n_edges for s in chunk)),
+                         self._edge_floor(node_bucket))
+                edges = np.zeros((bb, eb, 2), dtype=np.int32)
+                emask = np.zeros((bb, eb), dtype=np.float32)
+                pack_edges(chunk, eb, edges_out=edges[:b],
+                           mask_out=emask[:b])
+                batch["edges"] = jnp.asarray(edges)
+                batch["edge_mask"] = jnp.asarray(emask)
+            else:
+                adj = np.zeros((bb, node_bucket, node_bucket),
+                               dtype=np.float32)
+                for i, s in enumerate(chunk):
+                    dense_adj(s.edges, node_bucket, out=adj[i])
+                batch["adj"] = jnp.asarray(adj)
+        with self._lock:
+            fresh = (node_bucket, eb, bb) not in self._compiled_shapes
+            fn = self._infer_fn(node_bucket, bb, eb)
+        shape = dict(nodes=node_bucket, edges=eb or 0, batch=bb)
+        out = self._apply(fn, (self.params, batch), b,
+                          shape if fresh else None)
         with self._lock:
             self.stats.batches_run += 1
             self.stats.node_slots_total += bb * node_bucket
             self.stats.node_slots_real += sum(s.n_nodes for s in chunk)
         return out[:b]
+
+    @staticmethod
+    def _apply(fn, args, graphs: int, new_shape: Optional[dict]):
+        """Call a jitted apply and fetch its result to the host: under
+        ``dippm.compile`` (stats: the shape) when ``new_shape`` is given,
+        the shape's first call, else under ``dippm.run``."""
+        span = (spans.TraceAnnotation(spans.RUN, graphs=graphs)
+                if new_shape is None
+                else spans.TraceAnnotation(spans.COMPILE, **new_shape))
+        with span:
+            dev = fn(*args)
+            with spans.TraceAnnotation(spans.FETCH):
+                return np.asarray(dev)
 
     def _stage_packed(self, chunk: Sequence[GraphSample], p: int, q: int,
                       g: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -465,16 +486,18 @@ class PredictionEngine:
         feat = self.cfg.node_feat_dim
         sdim = self.cfg.static_dim
         o1, o2, o3, f_len, i_len = packed_staging_layout(self.cfg, p, q, g)
-        fbuf = np.zeros(f_len, self._stage_dtype)
-        ibuf = np.zeros(i_len, np.int32)
-        collate_packed(chunk, out={
-            "x": fbuf[:o1].reshape(p, feat),
-            "mask": fbuf[o1:o2],
-            "edge_mask": fbuf[o2:o3],
-            "static": fbuf[o3:].reshape(g, sdim),
-            "edges": ibuf[:2 * q].reshape(q, 2),
-            "graph_ids": ibuf[2 * q:],
-        })
+        with spans.TraceAnnotation(spans.STAGE, graphs=len(chunk), p=p, q=q,
+                                   g=g):
+            fbuf = np.zeros(f_len, self._stage_dtype)
+            ibuf = np.zeros(i_len, np.int32)
+            collate_packed(chunk, out={
+                "x": fbuf[:o1].reshape(p, feat),
+                "mask": fbuf[o1:o2],
+                "edge_mask": fbuf[o2:o3],
+                "static": fbuf[o3:].reshape(g, sdim),
+                "edges": ibuf[:2 * q].reshape(q, 2),
+                "graph_ids": ibuf[2 * q:],
+            })
         return fbuf, ibuf
 
     def _run_packed(self, chunk: Sequence[GraphSample]) -> np.ndarray:
@@ -488,8 +511,11 @@ class PredictionEngine:
         nb, eb, gb = self._budgets
         p, q, g = packed_shape(chunk, nb, eb, gb)
         fbuf, ibuf = self._stage_packed(chunk, p, q, g)
-        fn = self._packed_fn(p, q, g)
-        out = np.asarray(fn(self.params, fbuf, ibuf))
+        with self._lock:
+            fresh = ("packed", p, q, g) not in self._compiled_shapes
+            fn = self._packed_fn(p, q, g)
+        out = self._apply(fn, (self.params, fbuf, ibuf), len(chunk),
+                          dict(p=p, q=q, g=g) if fresh else None)
         with self._lock:
             self.stats.batches_run += 1
             self.stats.node_slots_total += p

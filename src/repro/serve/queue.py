@@ -139,7 +139,8 @@ class Request:
     stale failure path can never settle a *successor* flight for the
     same fingerprint. ``deadline`` is the absolute ``perf_counter``
     instant after which no stage should spend work on this request
-    (``None`` = wait forever).
+    (``None`` = wait forever). ``req`` is the service-wide request id
+    its trace spans carry (-1 when the request came without one).
     """
 
     sample: GraphSample
@@ -150,6 +151,7 @@ class Request:
     fp: Optional[str] = None
     flight: Optional[object] = None
     deadline: Optional[float] = None
+    req: int = -1
 
     def expired(self, now: Optional[float] = None) -> bool:
         if self.deadline is None:
@@ -214,14 +216,16 @@ class RequestQueue:
 
     def _append_locked(self, sample: GraphSample, meta: Dict[str, Any],
                        fp: Optional[str] = None, flight=None,
-                       deadline: Optional[float] = None) -> Request:
+                       deadline: Optional[float] = None,
+                       req_id: Optional[int] = None) -> Request:
         """Build + enqueue one request (caller holds the lock and has
         already checked closed/capacity) — the single construction path
         shared by :meth:`put` and :meth:`put_many`."""
         req = Request(sample=sample, meta=meta,
                       future=PredictionFuture(), seq=self._seq,
                       t_submit=time.perf_counter(), fp=fp, flight=flight,
-                      deadline=deadline)
+                      deadline=deadline,
+                      req=-1 if req_id is None else req_id)
         self._seq += 1
         self._items.append(req)
         self.peak_depth = max(self.peak_depth, len(self._items))
@@ -234,7 +238,8 @@ class RequestQueue:
 
     def put(self, sample: GraphSample, meta: Dict[str, Any],
             fp: Optional[str] = None, flight=None,
-            deadline: Optional[float] = None) -> Request:
+            deadline: Optional[float] = None,
+            req_id: Optional[int] = None) -> Request:
         """Enqueue; returns the :class:`Request` carrying a fresh future.
 
         When bounded and full: ``shed_policy="reject"`` raises
@@ -259,7 +264,8 @@ class RequestQueue:
                         f"requests) — admission control rejected the "
                         f"request; retry with backoff or raise "
                         f"ServeConfig.max_queue")
-            req = self._append_locked(sample, meta, fp, flight, deadline)
+            req = self._append_locked(sample, meta, fp, flight, deadline,
+                                      req_id)
             depth = len(self._items)
             if depth == 1 or (self.batch_hint is not None
                               and depth >= self.batch_hint):
@@ -270,7 +276,7 @@ class RequestQueue:
 
     def put_many(self, items) -> List[Request]:
         """Atomically enqueue a burst of
-        ``(sample, meta[, fp[, flight[, deadline]]])`` tuples.
+        ``(sample, meta[, fp[, flight[, deadline[, req_id]]]])`` tuples.
 
         All-or-nothing under admission control: if the burst doesn't fit
         a bounded queue, nothing is enqueued and
@@ -284,7 +290,7 @@ class RequestQueue:
         engine sweep would plan, instead of fragmenting across drains
         while later items are still being featurized.
         """
-        items = [(*it, *((None,) * (5 - len(it)))) for it in items]
+        items = [(*it, *((None,) * (6 - len(it)))) for it in items]
         shed: List[Request] = []
         with self._cond:
             if self._closed:
@@ -303,8 +309,7 @@ class RequestQueue:
                             f"the serving queue ({len(self._items)} "
                             f"waiting, cap {self.max_size}) — admission "
                             f"control rejected it")
-            reqs = [self._append_locked(sample, meta, fp, flight, deadline)
-                    for sample, meta, fp, flight, deadline in items]
+            reqs = [self._append_locked(*it) for it in items]
             if reqs:
                 self._cond.notify_all()
         if shed and self.on_shed is not None:

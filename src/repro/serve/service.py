@@ -84,6 +84,7 @@ error — no matter what fails underneath:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -94,6 +95,7 @@ import numpy as np
 from ..core.batching import (packed_rung_ladder, resolve_packed_budgets,
                              sample_from_graph)
 from ..core.engine import EngineConfig, PredictionEngine
+from ..core import spans
 from ..core.ir import GraphValidationError, OpGraph
 from .cache import CacheWaiter, PredictionCache
 from .fleet import NoHealthyReplicaError
@@ -301,6 +303,8 @@ class PredictionService:
         self._bisect_runs = 0
         self._invalid = 0
         self._latencies: deque = deque(maxlen=self.serve_cfg.latency_window)
+        #: Service-wide request ids: ``req`` on the trace spans.
+        self._req_ids = itertools.count()
         self._worker = threading.Thread(
             target=self._run, name="dippm-serve-batcher", daemon=True)
         self._worker.start()
@@ -348,6 +352,12 @@ class PredictionService:
         :class:`~repro.serve.lifecycle.ServiceDrainingError` after
         :meth:`drain` / :meth:`close`.
         """
+        req_id = next(self._req_ids)
+        with spans.TraceAnnotation(spans.SUBMIT, req=req_id):
+            return self._submit(g, deadline_ms, req_id)
+
+    def _submit(self, g: OpGraph, deadline_ms: Optional[float],
+                req_id: int) -> PredictionFuture:
         # admission stops at drain for EVERY path — a cache hit or
         # quarantine fast-fail must not slip past a closed queue
         if self._queue.closed:
@@ -359,7 +369,8 @@ class PredictionService:
         fp = None
         flight = None
         if self._cache is not None or self._quarantine is not None:
-            fp = g.fingerprint()
+            with spans.TraceAnnotation(spans.FINGERPRINT):
+                fp = g.fingerprint()
             fut = self._quarantine_fastfail(fp)
             if fut is not None:
                 with self._state:
@@ -377,9 +388,11 @@ class PredictionService:
                     self._resolve_waiter(waiter, y)
                 return fut
         ecfg = self.engine.engine_cfg
-        sample = sample_from_graph(g, buckets=ecfg.buckets,
-                                   extended_static=ecfg.extended_static)
-        return self._submit_sample(sample, meta, fp, flight, deadline)
+        with spans.TraceAnnotation(spans.FEATURISE):
+            sample = sample_from_graph(g, buckets=ecfg.buckets,
+                                       extended_static=ecfg.extended_static)
+        return self._submit_sample(sample, meta, fp, flight, deadline,
+                                   req_id)
 
     def submit_json(self, doc: Dict[str, Any],
                     deadline_ms: Optional[float] = None
@@ -395,17 +408,20 @@ class PredictionService:
         rejections.
         """
         from ..core.frontends import from_json
-        try:
-            g = from_json(doc)
-        except GraphValidationError as e:
-            fut = PredictionFuture()
-            fut._reject(e)
-            with self._state:
-                self._submitted += 1
-                self._failed += 1
-                self._invalid += 1
-            return fut
-        return self.submit(g, deadline_ms=deadline_ms)
+        req_id = next(self._req_ids)
+        with spans.TraceAnnotation(spans.SUBMIT, req=req_id):
+            try:
+                with spans.TraceAnnotation(spans.PARSE):
+                    g = from_json(doc)
+            except GraphValidationError as e:
+                fut = PredictionFuture()
+                fut._reject(e)
+                with self._state:
+                    self._submitted += 1
+                    self._failed += 1
+                    self._invalid += 1
+                return fut
+            return self._submit(g, deadline_ms, req_id)
 
     def submit_jax(self, forward, param_specs, *input_specs,
                    batch: Optional[int] = None,
@@ -421,12 +437,13 @@ class PredictionService:
         return self.submit(from_jax(forward, param_specs, *input_specs,
                                     meta=m), deadline_ms=deadline_ms)
 
-    def _submit_sample(self, sample, meta, fp: Optional[str] = None,
-                       flight=None,
-                       deadline: Optional[float] = None
-                       ) -> PredictionFuture:
+    def _submit_sample(self, sample, meta, fp: Optional[str],
+                       flight, deadline: Optional[float],
+                       req_id: int) -> PredictionFuture:
         try:
-            req = self._queue.put(sample, meta, fp, flight, deadline)
+            with spans.TraceAnnotation(spans.ENQUEUE):
+                req = self._queue.put(sample, meta, fp, flight, deadline,
+                                      req_id)
         except (QueueFullError, ServiceDrainingError) as e:
             # this request was the single-flight leader — clear the
             # flight (a leaked one would strand every future duplicate)
@@ -466,21 +483,25 @@ class PredictionService:
         deadline = self._deadline_at(deadline_ms)
 
         def _featurize(g):
-            return sample_from_graph(g, buckets=ecfg.buckets,
-                                     extended_static=ecfg.extended_static)
+            with spans.TraceAnnotation(spans.FEATURISE):
+                return sample_from_graph(
+                    g, buckets=ecfg.buckets,
+                    extended_static=ecfg.extended_static)
 
         # route every graph first: quarantined → already-rejected
         # future, hits/followers resolve without queue slots, leaders
         # featurize and enqueue in one transaction
         slots = []   # ("leader", item_idx, _) | ("hit"/"follower",
         #              waiter, y) | ("fastfail", fut, _)
-        items = []   # leaders: (sample, meta, fp, flight, deadline)
+        items = []   # leaders: (sample, meta, fp, flight, deadline, req_id)
         n_fast = 0
         for g in graphs:
+            req_id = next(self._req_ids)
             meta = dict(g.meta)
             fp = None
             if self._cache is not None or self._quarantine is not None:
-                fp = g.fingerprint()
+                with spans.TraceAnnotation(spans.FINGERPRINT):
+                    fp = g.fingerprint()
                 fut = self._quarantine_fastfail(fp)
                 if fut is not None:
                     slots.append(("fastfail", fut, None))
@@ -488,22 +509,25 @@ class PredictionService:
                     continue
             if self._cache is None:
                 slots.append(("leader", len(items), None))
-                items.append((_featurize(g), meta, fp, None, deadline))
+                items.append((_featurize(g), meta, fp, None, deadline,
+                              req_id))
                 continue
             fut = PredictionFuture()
             waiter = CacheWaiter(fut, meta, time.perf_counter(), deadline)
             status, y, flight = self._cache.claim(fp, waiter)
             if status == "leader":
                 slots.append(("leader", len(items), None))
-                items.append((_featurize(g), meta, fp, flight, deadline))
+                items.append((_featurize(g), meta, fp, flight, deadline,
+                              req_id))
             else:
                 slots.append((status, waiter, y))
         try:
-            reqs = self._queue.put_many(items)
+            with spans.TraceAnnotation(spans.ENQUEUE):
+                reqs = self._queue.put_many(items)
         except (QueueFullError, ServiceDrainingError) as e:
             n_rej = len(graphs) - n_fast
             if self._cache is not None:
-                for _, _, fp, flight, _ in items:
+                for _, _, fp, flight, _, _ in items:
                     for w in self._cache.abort(fp, flight):
                         w.future._reject(e)
                         n_rej += 1
@@ -753,12 +777,17 @@ class PredictionService:
     def _run(self) -> None:
         sc = self.serve_cfg
         while True:
-            batch, _depth = self._queue.wait_batch(
-                sc.max_batch_graphs, sc.max_wait_ms / 1e3)
+            with spans.TraceAnnotation(spans.BATCHER_WAIT):
+                batch, _depth = self._queue.wait_batch(
+                    sc.max_batch_graphs, sc.max_wait_ms / 1e3)
             if not batch:
                 return                          # closed and drained
             try:
-                self._process(batch)
+                ids = [r.req for r in batch]
+                with spans.TraceAnnotation(
+                        spans.DRAIN, requests=len(batch),
+                        req_first=min(ids), req_last=max(ids)) as span:
+                    self._process(batch, span)
             except Exception as e:              # pragma: no cover — belt
                 # _process guards itself; this keeps ANY escape from
                 # killing the batcher (a dead batcher hangs every
@@ -883,7 +912,9 @@ class PredictionService:
                     else:
                         stack.append((part, e2))
 
-    def _process(self, batch: List[Request]) -> None:
+    def _process(self, batch: List[Request], span) -> None:
+        """Run one drained batch and settle every future in it; ``span``
+        (the drain's trace span) gets the batch's total queue wait."""
         from ..core.predictor import make_prediction
         lats: List[float] = []
         done = failed = n_bins = 0
@@ -891,6 +922,8 @@ class PredictionService:
             # deadline sweep at drain time: requests that expired while
             # queued never cost a bin slot
             now = time.perf_counter()
+            span.set_metadata(queue_wait_ms=1e3 * sum(
+                now - r.t_submit for r in batch))
             live: List[Request] = []
             for r in batch:
                 if r.expired(now):
@@ -904,7 +937,9 @@ class PredictionService:
             # run_bin (bin count tracked locally — the engine may be
             # shared with concurrent direct callers, so diffing its
             # counters would over-count)
-            bins = self.engine.plan_bins(samples)
+            with spans.TraceAnnotation(spans.PLAN) as plan:
+                bins = self.engine.plan_bins(samples)
+                plan.set_metadata(bins=len(bins))
             n_bins = len(bins)
             ys = np.zeros((len(samples), self.engine.cfg.n_targets),
                           dtype=np.float32)
@@ -940,33 +975,34 @@ class PredictionService:
                         self._recover_chunk(keep, samples, ys, bin_err,
                                             bin_dl, e, live)
             t_done = time.perf_counter()
-            # batch is FIFO-drained, so walking it resolves futures in
-            # submission order; ys is already scattered to batch order
-            for j, (r, y) in enumerate(zip(live, ys)):
-                err = bin_err[j]
-                if err is not None:
-                    if isinstance(err, DeadlineExceededError):
-                        self._expire_request(r, err)
-                    else:
-                        self._fail_request(r, err)
+            with spans.TraceAnnotation(spans.RESOLVE, requests=len(live)):
+                # batch is FIFO-drained, so walking it resolves futures in
+                # submission order; ys is already scattered to batch order
+                for j, (r, y) in enumerate(zip(live, ys)):
+                    err = bin_err[j]
+                    if err is not None:
+                        if isinstance(err, DeadlineExceededError):
+                            self._expire_request(r, err)
+                        else:
+                            self._fail_request(r, err)
+                            failed += 1
+                        continue
+                    lat_ms = (t_done - r.t_submit) * 1e3
+                    try:
+                        pred = make_prediction(y, meta=r.meta)
+                    except Exception as e:      # a bad row fails one future
+                        self._fail_request(r, e)
                         failed += 1
-                    continue
-                lat_ms = (t_done - r.t_submit) * 1e3
-                try:
-                    pred = make_prediction(y, meta=r.meta)
-                except Exception as e:          # a bad row fails one future
-                    self._fail_request(r, e)
-                    failed += 1
-                    continue
-                lats.append(lat_ms)
-                done += 1
-                r.future._resolve(pred, lat_ms)
-                if self._cache is not None and r.fp is not None:
-                    # populate the cache and release this fingerprint's
-                    # coalesced followers with the same vector (scoped
-                    # to this request's flight token)
-                    for w in self._cache.complete(r.fp, y, r.flight):
-                        self._resolve_waiter(w, y, t_done)
+                        continue
+                    lats.append(lat_ms)
+                    done += 1
+                    r.future._resolve(pred, lat_ms)
+                    if self._cache is not None and r.fp is not None:
+                        # populate the cache and release this fingerprint's
+                        # coalesced followers with the same vector (scoped
+                        # to this request's flight token)
+                        for w in self._cache.complete(r.fp, y, r.flight):
+                            self._resolve_waiter(w, y, t_done)
         except Exception as e:                  # resolve, never hang callers
             for r in batch:
                 if not r.future.done():

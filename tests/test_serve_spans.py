@@ -1,0 +1,129 @@
+"""Trace spans of the serving path (``repro.core.spans``): what a
+profiler session records, and that recording changes no answer."""
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+from repro.core import DIPPM, PMGNSConfig, PredictionEngine, pmgns_init
+from repro.core import spans as span_names
+from repro.core.batching import sample_from_graph
+from repro.core.ir import OpGraph, OpNode
+
+
+def _graph(n_nodes, seed=0):
+    rng = np.random.default_rng(seed)
+    ops = ["dense", "conv", "relu", "add"]
+    nodes = [OpNode(i, ops[i % len(ops)],
+                    (int(rng.integers(1, 16)), int(rng.integers(1, 64))),
+                    flops=float(rng.integers(1, 10_000)),
+                    macs=float(rng.integers(1, 5_000)))
+             for i in range(n_nodes)]
+    edges = [(i, i + 1) for i in range(n_nodes - 1)]
+    return OpGraph(nodes=nodes, edges=edges, meta={"seed": seed})
+
+
+GRAPHS = [_graph(n, seed=i) for i, n in enumerate([5, 40, 100, 7, 60, 12])]
+
+
+@pytest.fixture(scope="module")
+def packed_dippm():
+    cfg = PMGNSConfig(hidden=32, layout="packed")
+    return DIPPM.from_params(pmgns_init(jax.random.PRNGKey(0), cfg), cfg)
+
+
+def traced(fn, tmp_path):
+    """Run ``fn`` under a profiler session; returns its result and the
+    ``dippm.*`` host events as ``(name, line, start_ns, dur_ns,
+    stats)``."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[-1]
+    events, line = [], 0
+    for pl in ProfileData.from_file(path).planes:
+        if not pl.name.startswith("/host:"):
+            continue
+        for ln in pl.lines:
+            for e in ln.events:
+                if e.name.startswith("dippm."):
+                    events.append((e.name, line, e.start_ns, e.duration_ns,
+                                   dict(e.stats)))
+            line += 1
+    return out, events
+
+
+def _serve_all(dippm):
+    """Every graph through ``submit_json``, then three through
+    ``submit_many``: two drains, each held open until its flush, so the
+    bins do not depend on timing."""
+    with dippm.serve(max_wait_ms=60_000.0, cache_size=None) as svc:
+        futs = [svc.submit_json(g.to_json()) for g in GRAPHS]
+        svc.flush()
+        out = [f.result(60) for f in futs]
+        futs = svc.submit_many(GRAPHS[:3])
+        svc.flush()
+        return out + [f.result(60) for f in futs]
+
+
+def test_answers_are_bit_identical_with_the_profiler_on(packed_dippm,
+                                                        tmp_path):
+    off = _serve_all(packed_dippm)
+    on, events = traced(lambda: _serve_all(packed_dippm), tmp_path)
+    assert events
+    assert on == off
+
+
+def test_one_submit_span_per_request_with_its_parts(packed_dippm, tmp_path):
+    _, events = traced(lambda: _serve_all(packed_dippm), tmp_path)
+    names = [e[0] for e in events]
+    n_json, n_many = len(GRAPHS), 3
+    assert names.count(span_names.SUBMIT) == n_json     # not one more
+    assert names.count(span_names.PARSE) == n_json
+    for name in (span_names.FINGERPRINT, span_names.FEATURISE):
+        assert names.count(name) == n_json + n_many
+    assert names.count(span_names.ENQUEUE) == n_json + 1
+    reqs = sorted(e[4]["req"] for e in events if e[0] == span_names.SUBMIT)
+    assert reqs == list(range(n_json))
+    drains = [e[4] for e in events if e[0] == span_names.DRAIN]
+    assert [d["requests"] for d in drains] == [n_json, n_many]
+    assert min(d["req_first"] for d in drains) == 0
+    assert max(d["req_last"] for d in drains) == n_json + n_many - 1
+    assert all(d["queue_wait_ms"] >= 0 for d in drains)
+    resolved = [e[4]["requests"] for e in events
+                if e[0] == span_names.RESOLVE]
+    assert sum(resolved) == n_json + n_many
+
+
+@pytest.mark.parametrize("layout", ["packed", "sparse", "dense"])
+def test_first_call_of_a_shape_is_a_compile_span(layout, tmp_path):
+    cfg = PMGNSConfig(hidden=16, layout=layout)
+    eng = PredictionEngine(pmgns_init(jax.random.PRNGKey(1), cfg), cfg)
+    chunk = [sample_from_graph(g, buckets=eng.engine_cfg.buckets)
+             for g in (GRAPHS[0], GRAPHS[3])]     # one node bucket
+
+    def twice():
+        return eng.run_bin(chunk), eng.run_bin(chunk)
+
+    (a, b), events = traced(twice, tmp_path)
+    np.testing.assert_array_equal(a, b)
+    names = [e[0] for e in events]
+    assert names == [span_names.STAGE, span_names.COMPILE, span_names.FETCH,
+                     span_names.STAGE, span_names.RUN, span_names.FETCH]
+    stage, compile_, run = events[0], events[1], events[4]
+    assert stage[4]["graphs"] == 2 and run[4] == {"graphs": 2}
+    if layout == "packed":
+        assert set(stage[4]) == set(compile_[4]) | {"graphs"} == \
+            {"graphs", "p", "q", "g"}
+    else:
+        assert set(compile_[4]) == {"nodes", "edges", "batch"}
+    fetch = events[5]
+    assert run[2] <= fetch[2] and \
+        fetch[2] + fetch[3] <= run[2] + run[3]     # fetch inside run
