@@ -19,8 +19,11 @@ Design notes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
+import itertools
 import json
+from operator import attrgetter
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -94,8 +97,103 @@ class GraphValidationError(ValueError):
 #: Weisfeiler–Lehman refinement rounds behind :meth:`OpGraph.fingerprint`.
 #: Each round folds one more hop of wiring into every node label; 4 rounds
 #: separate any two operator DAGs whose 4-hop neighborhoods differ, at
-#: O(rounds · (n + e)) hashing cost.
+#: O(rounds · (n + e)) array work.
 _WL_ROUNDS = 4
+
+
+def _u64(x: int) -> np.ndarray:
+    # a 0-d array: numpy binds it to an array operand faster than a scalar
+    return np.array(x, np.uint64)
+
+
+#: splitmix64's finalizer (Steele, Lea & Flood, "Fast splittable
+#: pseudorandom number generators", OOPSLA 2014).
+_MIX_MUL = (_u64(0xBF58476D1CE4E5B9), _u64(0x94D049BB133111EB))
+_MIX_SHIFT = (_u64(30), _u64(27), _u64(31))
+#: Salts that set a shape dim's position and an edge's source apart.
+_DIM_SALT = _u64(0x9E3779B97F4A7C15)
+_PAIR_SALT = _u64(0xD6E8FEB86659FD93)
+#: Weight of the successor sum; being 3 mod 4, it keeps apart two nodes
+#: whose predecessor and successor sums are swapped (unless the sums
+#: agree in 63 bits).
+_SUCC_MUL = _u64(0xC2B2AE3D27D4EB4F)
+_CONTENT_KEY = attrgetter("op", "out_shape", "dtype")
+_NODE_ID = attrgetter("node_id")
+
+
+def _mix64(z: np.ndarray) -> np.ndarray:
+    """splitmix64's finalizer over a ``uint64`` array: a bijection that
+    spreads every input bit over the whole word (wraps mod 2^64)."""
+    z = z ^ (z >> _MIX_SHIFT[0])
+    z *= _MIX_MUL[0]
+    z ^= z >> _MIX_SHIFT[1]
+    z *= _MIX_MUL[1]
+    z ^= z >> _MIX_SHIFT[2]
+    return z
+
+
+@functools.lru_cache(maxsize=1024)
+def _op_dtype_word(op: str, dtype: str) -> int:
+    """A stable 64-bit hash of an ``(op, dtype)`` pair (graphs hold a
+    few dozen distinct pairs)."""
+    return int.from_bytes(hashlib.blake2b(f"{op}|{dtype}".encode(),
+                                          digest_size=8).digest(), "little")
+
+
+def _content_labels(nodes: Sequence["OpNode"]) -> np.ndarray:
+    """Each node's initial WL label, a ``uint64`` hash of its content
+    ``(op, out_shape, dtype)``: the op/dtype word, the rank, and the sum
+    of the dims each mixed with its position. Hashed once per distinct
+    content in the graph (a few dozen across hundreds of nodes)."""
+    n = len(nodes)
+    keys = list(map(_CONTENT_KEY, nodes))
+    first: Dict[Tuple[Any, ...], int] = {}   # content → its first node
+    try:
+        first_of = np.fromiter(map(first.setdefault, keys, range(n)),
+                               np.intp, n)
+    except TypeError:  # an out_shape given as a list
+        first.clear()
+        keys = [(op, tuple(shape), dtype) for op, shape, dtype in keys]
+        first_of = np.fromiter(map(first.setdefault, keys, range(n)),
+                               np.intp, n)
+    k = len(first)
+    ops, shapes, dtypes = zip(*first) if k else ((), (), ())
+    rank = np.fromiter(map(len, shapes), np.int64, k)
+    dims = np.fromiter(itertools.chain.from_iterable(shapes), np.int64,
+                       int(rank.sum())).view(np.uint64)
+    owner = np.repeat(np.arange(k), rank)
+    pos = np.arange(dims.size) - (np.cumsum(rank) - rank)[owner]
+    shape_sum = np.zeros(k, np.uint64)
+    np.add.at(shape_sum, owner,
+              _mix64(dims + _mix64(pos.view(np.uint64) ^ _DIM_SALT)))
+    word = np.fromiter(map(_op_dtype_word, ops, dtypes), np.uint64, k)
+    labels = np.empty(n, np.uint64)
+    labels[np.fromiter(first.values(), np.intp, k)] = _mix64(
+        _mix64(word + rank.view(np.uint64)) + shape_sum)
+    return labels[first_of]
+
+
+def _edge_positions(nodes: Sequence["OpNode"],
+                    edges: Sequence[Tuple[int, int]]
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """The list positions of the edges' sources and destinations;
+    ``KeyError`` names an edge end that is not a node id."""
+    n = len(nodes)
+    ends = np.fromiter(itertools.chain.from_iterable(edges), np.int64,
+                       2 * len(edges)).reshape(-1, 2)
+    if list(map(_NODE_ID, nodes)) == list(range(n)):
+        # ids 0..n-1 in list order (what filter_and_preprocess emits)
+        if ends.size and not 0 <= ends.min() <= ends.max() < n:
+            raise KeyError(int(ends[(ends < 0) | (ends >= n)][0]))
+        return ends[:, 0], ends[:, 1]
+    ids = np.fromiter(map(_NODE_ID, nodes), np.int64, n)
+    order = np.argsort(ids, kind="stable")
+    at = np.searchsorted(ids[order], ends).clip(max=n - 1)
+    known = ids[order][at] == ends
+    if not known.all():
+        raise KeyError(int(ends[~known][0]))
+    at = order[at]
+    return at[:, 0], at[:, 1]
 
 
 # ---------------------------------------------------------------------------
@@ -227,66 +325,71 @@ class OpGraph:
         """Canonical content hash — invariant under node reordering.
 
         Two :class:`OpGraph`\\ s describing the same model must hash
-        equal even when their node lists are permuted or their (dense)
-        ids relabeled — frontends that re-parse a serialized graph can
-        emit nodes in a different order, and the serving layer's
-        content-addressed prediction cache (``repro.serve.cache``) keys
-        on this hash, so an order-sensitive fingerprint would silently
-        miss on every re-parsed duplicate.
+        equal even when their node lists are permuted or their ids
+        relabeled — frontends that re-parse a serialized graph can emit
+        nodes in a different order, and the serving layer's
+        content-addressed prediction cache (``repro.serve.cache``) and
+        poison quarantine key on this hash, so an order-sensitive
+        fingerprint would silently miss on every re-parsed duplicate.
 
-        The hash is built from permutation-invariant views only:
+        Weisfeiler–Lehman refinement over the edge arrays, in numpy:
 
-        1. a per-node content label ``(op, out_shape, dtype)``, refined
-           for a few Weisfeiler–Lehman rounds over the sorted multisets
-           of predecessor/successor labels (so a node's label encodes
-           its local wiring, not its position);
-        2. the sorted multiset of final node labels;
-        3. the sorted multiset of edge ``(src_label, dst_label)`` pairs;
-        4. node/edge counts and the JSON-canonicalized ``meta``.
+        1. every node starts from a 64-bit label of its content
+           ``(op, out_shape, dtype)``;
+        2. each of :data:`_WL_ROUNDS` rounds mixes into every label the
+           **commutative sum** (mod 2^64) of its predecessors' mixed
+           labels and, with another weight, the same sum over its
+           successors. The sum is a multiset hash: it ignores neighbour
+           order and counts duplicate edges with their multiplicity, so
+           a node's label encodes its local wiring, not its position;
+        3. ``sha256`` over the node/edge counts, the sum of the final
+           labels, the sum of the mixed ``(src_label, dst_label)`` edge
+           pairs (both multiset hashes, little-endian, so the value does
+           not depend on the host) and the JSON-canonicalized ``meta``,
+           as 64 hex digits.
+
+        There is no sort, and each distinct content is hashed once:
+        numpy lets go of the GIL in every sort (and in ``np.add.at`` and
+        loops over more than 500 elements), and in a serving process the
+        batcher thread then takes it in the middle of a submit. Edge
+        ends must name node ids; an unknown id raises ``KeyError``.
+
+        The array version replaced a per-node ``blake2b`` over sorted
+        neighbour labels. It induces the same equivalence classes (the
+        tests keep that version as a reference), but every value changed
+        once: the cost model's noise realisation per graph and the
+        splits of a freshly built dataset changed with it, while records
+        that stash ``meta["fingerprint"]`` keep theirs.
 
         WL-indistinguishable non-isomorphic graphs could in principle
         collide, but operator DAGs with shaped, typed nodes don't hit
         those pathologies in practice; for cache keys the failure mode
-        is astronomically unlikely (and bounded by sha256 anyway).
+        is astronomically unlikely.
 
         The hash is memoized on the instance: graphs are treated as
         immutable once built (every transform in this repo constructs a
         new ``OpGraph``), and both the serving cache and the cost
-        model's noise seeding hit this per request — recomputing the WL
-        refinement each time would cost more than a cache hit saves.
+        model's noise seeding hit this per request.
         """
         memo = self.__dict__.get("_fingerprint")
         if memo is not None:
             return memo
-        n = len(self.nodes)
-        pos = {nd.node_id: i for i, nd in enumerate(self.nodes)}
-
-        def _h(data: bytes) -> bytes:
-            return hashlib.blake2b(data, digest_size=16).digest()
-
-        labels = [_h(f"{nd.op}|{tuple(nd.out_shape)}|{nd.dtype}".encode())
-                  for nd in self.nodes]
-        preds: List[List[int]] = [[] for _ in range(n)]
-        succs: List[List[int]] = [[] for _ in range(n)]
-        edge_pos = []
-        for s, d in self.edges:
-            si, di = pos[s], pos[d]
-            preds[di].append(si)
-            succs[si].append(di)
-            edge_pos.append((si, di))
-        for _ in range(_WL_ROUNDS):
-            labels = [
-                _h(labels[i]
-                   + b"<" + b"".join(sorted(labels[p] for p in preds[i]))
-                   + b">" + b"".join(sorted(labels[q] for q in succs[i])))
-                for i in range(n)
-            ]
-        h = hashlib.sha256()
-        h.update(f"{n}|{len(self.edges)}".encode())
-        for lab in sorted(labels):
-            h.update(lab)
-        for pair in sorted(labels[si] + labels[di] for si, di in edge_pos):
-            h.update(pair)
+        n, e = len(self.nodes), len(self.edges)
+        src, dst = _edge_positions(self.nodes, self.edges)
+        with np.errstate(over="ignore"):
+            labels = _content_labels(self.nodes)
+            for _ in range(_WL_ROUNDS):
+                mixed = _mix64(labels)
+                into = np.zeros(n, np.uint64)
+                np.add.at(into, dst, mixed[src])
+                out = np.zeros(n, np.uint64)
+                np.add.at(out, src, mixed[dst])
+                labels = _mix64(labels + into + out * _SUCC_MUL)
+            pairs = _mix64(_mix64(labels[src] ^ _PAIR_SALT) + labels[dst])
+            sums = np.array([labels.sum(dtype=np.uint64),
+                             pairs.sum(dtype=np.uint64)], "<u8")
+        h = hashlib.sha256(f"{n}|{e}".encode())
+        h.update(sums.tobytes())
         h.update(json.dumps(self.meta, sort_keys=True, default=str).encode())
         fp = h.hexdigest()
         self.__dict__["_fingerprint"] = fp
