@@ -4,9 +4,11 @@ Independent of the program under test: it reads the request's JSON
 document itself, builds the paper's node features (§3.2, Algorithm 1)
 and static features (§3.3, eq. 1) with numpy, and runs the PMGNS forward
 pass (§3.4) for one graph at a time in plain ``jax.numpy``: no kernels,
-no packing into bins, no staging buffers. Each graph is padded to one
-fixed size with masks, so a block of graphs runs as one ``vmap`` of the
-per-graph function and compiles once.
+no packing into bins, no staging buffers. Each graph is padded with
+masks to at least ``N_PAD`` nodes and ``E_PAD`` edges, or to the next
+power of two above its size, and the graphs of one padded shape run in
+blocks, each one ``vmap`` of the per-graph function: every graph of the
+zoo pool shares one compiled shape, and a graph of any size computes.
 
 Float32 under matmul precision ``"highest"`` is the reference. The
 control is the same code with every matrix product computed as three
@@ -19,7 +21,7 @@ same on every backend.
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -35,11 +37,14 @@ DTYPE_BYTES = {"float64": 8, "float32": 4, "float16": 2, "bfloat16": 2,
 NODE_FEATURE_DIM = 32
 STATIC_FEATURE_DIM = 5
 
-#: Every pool graph fits these (the pool keeps graphs of at most 1,024
-#: nodes; the densest has under 2,048 edges).
+#: The smallest padded shape: every zoo pool graph fits it (at most
+#: 1,024 nodes; the densest has under 2,048 edges). A larger graph pads
+#: each axis to the next power of two.
 N_PAD = 1024
 E_PAD = 2048
-#: Graphs per compiled reference call.
+#: Graphs per compiled reference call at the smallest shape; a call
+#: holds at most ``BLOCK * N_PAD`` node rows, so larger shapes run
+#: fewer graphs a call, down to one.
 BLOCK = 32
 
 
@@ -87,20 +92,31 @@ def featurise(doc: Dict) -> Dict[str, np.ndarray]:
             "static": np.asarray(static, np.float32)}
 
 
-def padded(feats: Sequence[Dict[str, np.ndarray]]) -> Dict[str, np.ndarray]:
-    """Stack per-graph features, each padded to ``N_PAD`` nodes and
-    ``E_PAD`` edges; padding is masked out of every sum, mean and max."""
-    b = len(feats)
-    out = {"x": np.zeros((b, N_PAD, NODE_FEATURE_DIM), np.float32),
-           "node_mask": np.zeros((b, N_PAD), np.float32),
-           "edges": np.zeros((b, E_PAD, 2), np.int32),
-           "edge_mask": np.zeros((b, E_PAD), np.float32),
+def _pow2(n: int) -> int:
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
+def pad_shape(f: Dict[str, np.ndarray]) -> Tuple[int, int]:
+    """``(nodes, edges)`` that the graph of features ``f`` is padded to."""
+    return (max(N_PAD, _pow2(len(f["x"]))),
+            max(E_PAD, _pow2(len(f["edges"]))))
+
+
+def padded(feats: Sequence[Dict[str, np.ndarray]],
+           shape: Tuple[int, int]) -> Dict[str, np.ndarray]:
+    """Stack per-graph features, each padded to ``shape`` (nodes,
+    edges); padding is masked out of every sum, mean and max."""
+    b, (n_pad, e_pad) = len(feats), shape
+    out = {"x": np.zeros((b, n_pad, NODE_FEATURE_DIM), np.float32),
+           "node_mask": np.zeros((b, n_pad), np.float32),
+           "edges": np.zeros((b, e_pad, 2), np.int32),
+           "edge_mask": np.zeros((b, e_pad), np.float32),
            "static": np.zeros((b, STATIC_FEATURE_DIM), np.float32)}
     for i, f in enumerate(feats):
         n, e = len(f["x"]), len(f["edges"])
-        if n > N_PAD or e > E_PAD:
+        if n > n_pad or e > e_pad:
             raise ValueError(f"graph of {n} nodes, {e} edges exceeds the "
-                             f"reference's {N_PAD}/{E_PAD}")
+                             f"padded shape {n_pad}/{e_pad}")
         out["x"][i, :n] = f["x"]
         out["node_mask"][i, :n] = 1.0
         out["edges"][i, :e] = f["edges"]
@@ -178,8 +194,10 @@ _COMPILED: Dict = {}
 
 def forward_log(params, variant: str, feats: Sequence[Dict[str, np.ndarray]],
                 control: bool = False) -> np.ndarray:
-    """``[len(feats), 3]`` log1p-space targets, ``BLOCK`` graphs a call:
-    the reference, or with ``control`` the control."""
+    """``[len(feats), 3]`` log1p-space targets: the reference, or with
+    ``control`` the control. Graphs are grouped by :func:`pad_shape`;
+    each group runs in calls of ``max(1, BLOCK * N_PAD // nodes)``
+    graphs, the last filled up with repeats, so a shape compiles once."""
     import jax
     key = (variant, control)
     fn = _COMPILED.get(key)
@@ -191,17 +209,22 @@ def forward_log(params, variant: str, feats: Sequence[Dict[str, np.ndarray]],
                 lambda *a: _graph_forward(p, variant, *a, mm=mm))(
                 x, edges, edge_mask, node_mask, static)
         fn = _COMPILED[key] = jax.jit(block)
-    out: List[np.ndarray] = []
-    for s in range(0, len(feats), BLOCK):
-        chunk = list(feats[s:s + BLOCK])
-        real = len(chunk)
-        chunk += [chunk[-1]] * (BLOCK - real)        # one compiled shape
-        b = padded(chunk)
-        with jax.default_matmul_precision("highest"):
-            y = fn(params, b["x"], b["edges"], b["edge_mask"],
-                   b["node_mask"], b["static"])
-        out.append(np.asarray(y)[:real])
-    return np.concatenate(out) if out else np.zeros((0, 3), np.float32)
+    out = np.zeros((len(feats), 3), np.float32)
+    groups: Dict[Tuple[int, int], List[int]] = {}
+    for i, f in enumerate(feats):
+        groups.setdefault(pad_shape(f), []).append(i)
+    for shape, idx in groups.items():
+        block = max(1, BLOCK * N_PAD // shape[0])
+        for s in range(0, len(idx), block):
+            rows = idx[s:s + block]
+            chunk = [feats[i] for i in rows]
+            chunk += [chunk[-1]] * (block - len(rows))  # one compiled shape
+            b = padded(chunk, shape)
+            with jax.default_matmul_precision("highest"):
+                y = fn(params, b["x"], b["edges"], b["edge_mask"],
+                       b["node_mask"], b["static"])
+            out[rows] = np.asarray(y)[:len(rows)]
+    return out
 
 
 def served_gap(served_phys, ref_log) -> np.ndarray:

@@ -10,13 +10,16 @@ sits in a file of its own (``bench/configs/``, ``bench/traffic/``,
 not an edit here.
 
 A run: checks for the chips (none, or too few, exits 1 with no result);
-sets up the prediction service with weights made from ``--seed``, warms
-every program shape the traffic can reach and sends the traffic untimed
+sets up the prediction service with weights made from ``--seed`` (and
+the configuration's ``"weights"`` calibration, if any), warms every
+program shape the traffic can reach and sends the traffic untimed
 until no new shape compiles; then sends it for ``--seconds`` seconds and
 measures. With ``--trace 1`` it also records a profiler trace of part of
 the window and reports the per-layer metrics instead of the end-to-end
 ones. After the window it compares a seeded sample of the finished
-predictions with the plain reference (``bench/reference.py``) and prints
+predictions with the plain reference that the configuration file names
+(its ``"reference"``, ``bench/reference.py`` for both PMGNS
+configurations), at any graph size, and prints
 each compared number beside its limit, last on standard error and under
 ``checks`` in the result. The last line of standard output is the
 result, one JSON object. Each window also logs, on standard error, its
@@ -56,7 +59,6 @@ sys.path.insert(0, str(ROOT / "src"))
 import counts                                                # noqa: E402
 import loadgen                                               # noqa: E402
 import peaks as peaks_mod                                    # noqa: E402
-import reference                                             # noqa: E402
 import trace_reduce                                          # noqa: E402
 import weights                                               # noqa: E402
 
@@ -99,13 +101,27 @@ def cell_metrics(spec: Dict, cell: str, trace: bool) -> List[Dict]:
     return [m for m in entries if cell in m.get("workloads", [cell])]
 
 
-def load_reader(name: str) -> Callable:
-    path = BENCH / "metrics" / f"{name}.py"
+def load_module(path: Path, prefix: str, name: str) -> types.ModuleType:
     mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str) -> Callable:
+    return load_module(BENCH / "metrics" / f"{name}.py", "bench_metric_",
+                       name).read
+
+
+def load_reference(root: Path, config: Dict,
+                   name: str) -> types.ModuleType:
+    """The plain reference module at the configuration's ``"reference"``
+    path, relative to ``root``: its ``featurise``, ``forward_log`` and
+    ``served_gap`` decide ``correct``."""
+    if "reference" not in config:
+        raise SystemExit(f"configuration {name!r} names no \"reference\"")
+    return load_module(root / config["reference"], "bench_reference_", name)
 
 
 def check_chips(chips: int, require_chip: bool = True):
@@ -165,7 +181,7 @@ def counters(svc) -> Dict[str, float]:
             "node_slots_real": est.node_slots_real}
 
 
-def warm_escapes(svc, pool: List[str], sizes: List[tuple]) -> int:
+def warm_escapes(svc, pool: List[str], sizes: List[tuple]) -> List[tuple]:
     """Compile, on every engine, each bin shape whose edge count
     escalates past its rung's edge budget and that the pool can fill.
 
@@ -204,7 +220,46 @@ def warm_escapes(svc, pool: List[str], sizes: List[tuple]) -> int:
         for e in engines:
             e.run_bin(samples)
         shapes.append(packed_shape(samples, nb, eb, gb))
-    return len(shapes)
+    return shapes
+
+
+def warm_lone_bins(svc, pool: List[str], sizes: List[tuple],
+                   known: List[tuple]) -> List[tuple]:
+    """Compile, on every engine, each shape that a bin of one pool graph
+    takes outside the rung ladder and ``known`` (the escapes run).
+
+    A graph over a budget runs as a lone bin padded to powers of two,
+    which neither ``warmup`` nor :func:`warm_escapes` compiles. The
+    shape comes from the program's own sample of the graph, so it is
+    right whether or not the program cuts the graph. A graph that the
+    program keeps whole (no more nodes than its largest node bucket)
+    has no more nodes or edges in its sample than in its document, so
+    where the document's own shape is known its bin's is too, and it is
+    not featurised. Returns the shapes run."""
+    from repro.core.batching import (packed_rung_ladder, packed_shape,
+                                     resolve_packed_budgets,
+                                     sample_from_graph)
+    from repro.core.frontends import from_json
+    ec = svc.engine.engine_cfg
+    nb, eb, gb = resolve_packed_budgets(ec.node_budget, ec.edge_budget,
+                                        ec.graph_budget)
+    known = set(packed_rung_ladder(nb, eb, gb)) | set(known)
+    engines = list(getattr(svc.engine, "replicas", None) or [svc.engine])
+    lone: Dict[tuple, object] = {}
+    for line, (n, e) in zip(pool, sizes):
+        whole = types.SimpleNamespace(n_nodes=n, n_edges=e)
+        if n <= ec.buckets[-1] and packed_shape([whole], nb, eb, gb) in known:
+            continue
+        sample = sample_from_graph(from_json(json.loads(line)),
+                                   buckets=ec.buckets,
+                                   extended_static=ec.extended_static)
+        shape = packed_shape([sample], nb, eb, gb)
+        if shape not in known:
+            lone.setdefault(shape, sample)
+    for sample in lone.values():
+        for e in engines:
+            e.run_bin([sample])
+    return list(lone)
 
 
 class GcClock:
@@ -252,31 +307,40 @@ def sample_finished(rec: loadgen.Requests, idx: List[int], sizes,
     return sorted(pick)
 
 
-def compare(rec: loadgen.Requests, sample: List[int], pool: List[str],
-            params, model: Dict, control: bool) -> np.ndarray:
-    """Per-sample widest log1p gaps against the reference of the served
+def compare(reference: types.ModuleType, rec: loadgen.Requests,
+            sample: List[int], pool: List[str], params, model: Dict,
+            control: bool) -> np.ndarray:
+    """Per-sample widest log1p gaps against ``reference`` of the served
     predictions or, with ``control``, of the control's answers in their
-    place (the served gap is then logged beside it)."""
+    place (the served gap is then logged beside it). Each distinct pool
+    document of the sample is featurised and computed once."""
     if not sample:
         return np.zeros(0)
-    feats = [reference.featurise(json.loads(pool[rec.pool_idx[i]]))
-             for i in sample]
+    docs = sorted({rec.pool_idx[i] for i in sample})
+    at = {d: k for k, d in enumerate(docs)}
+    row = np.array([at[rec.pool_idx[i]] for i in sample])
+    t = time.perf_counter()
+    feats = [reference.featurise(json.loads(pool[d])) for d in docs]
     served = np.array([[p.latency_ms, p.energy_j, p.memory_mb]
                        for p in (rec.futures[i].result(0) for i in sample)])
-    ref = reference.forward_log(params, model["variant"], feats)
-    log(f"reference outputs: {float(ref.min())} to {float(ref.max())}")
+    ref = reference.forward_log(params, model["variant"], feats)[row]
+    log(f"reference over {len(docs)} distinct graphs in "
+        f"{time.perf_counter() - t:.3f} s; outputs: {float(ref.min())} "
+        f"to {float(ref.max())}")
     gaps = reference.served_gap(served, ref)
-    log_gaps("served", served, ref)
+    log_gaps(reference, "served", served, ref)
     if not control:
         return gaps
     log(f"served max_log_gap: {float(gaps.max())}")
     low = np.expm1(reference.forward_log(params, model["variant"], feats,
-                                         control=True).astype(np.float64))
-    log_gaps("control", low, ref)
+                                         control=True)[row]
+                   .astype(np.float64))
+    log_gaps(reference, "control", low, ref)
     return reference.served_gap(low, ref)
 
 
-def log_gaps(name: str, phys: np.ndarray, ref: np.ndarray) -> None:
+def log_gaps(reference: types.ModuleType, name: str, phys: np.ndarray,
+             ref: np.ndarray) -> None:
     """Log how the per-graph gaps of ``phys`` spread, and each target's
     widest gap."""
     gaps = reference.served_gap(phys, ref)
@@ -307,7 +371,10 @@ class Session:
         conf_entry = find(spec["configs"], self.cell["config"], "config")
         with open(root / conf_entry["file"]) as f:
             self.config = json.load(f)
-        with open(BENCH / "traffic" / f"{self.cell['traffic']}.json") as f:
+        self.reference = load_reference(root, self.config,
+                                        conf_entry["name"])
+        with open(root / BENCH.name / "traffic"
+                  / f"{self.cell['traffic']}.json") as f:
             self.traffic = json.load(f)
         self.model = {**self.config["model"], **(model_overrides or {})}
         self.seed, self.root = seed, root
@@ -323,7 +390,8 @@ class Session:
         from repro.core.gnn import PMGNSConfig
         from repro.core.predictor import DIPPM
 
-        self.params = weights.make_params(seed, self.model)
+        self.params = weights.make_params(
+            seed, self.model, **self.config.get("weights", {}))
         self.pool = loadgen.load_pool(root / self.traffic["pool"])
         self.sizes = [loadgen.graph_size(line) for line in self.pool]
         serve_kw = {**self.config.get("serve", {}),
@@ -334,7 +402,8 @@ class Session:
         try:
             self.inst = Instruments(self.svc)
             n_rungs = self.svc.warmup(rungs="all")
-            n_escapes = warm_escapes(self.svc, self.pool, self.sizes)
+            escapes = warm_escapes(self.svc, self.pool, self.sizes)
+            lone = warm_lone_bins(self.svc, self.pool, self.sizes, escapes)
             if fault is not None:
                 fault(self.svc)
             warm_s = float(self.traffic.get("warm_s", 3.0))
@@ -346,7 +415,8 @@ class Session:
         except BaseException:
             self.close()
             raise
-        log(f"set-up: {n_rungs} rungs, {n_escapes} escape shapes, "
+        log(f"set-up: {n_rungs} rungs, {len(escapes)} escape shapes, "
+            f"{len(lone)} lone shapes, "
             f"{n_pass} warm pass(es) of {warm_s} s")
 
     def window(self, seconds: float, stream: int = 0, tracer=None,
@@ -425,8 +495,8 @@ def run(args, *, require_chip: bool = True, root: Path = ROOT,
                 and not math.isnan(rec.done[i]) and rec.done[i] <= w.t1]
     sample = sample_finished(rec, finished, sess.sizes,
                              np.random.default_rng([args.seed, 3]))
-    gaps = compare(rec, sample, sess.pool, sess.params, sess.model,
-                   bool(args.control))
+    gaps = compare(sess.reference, rec, sample, sess.pool, sess.params,
+                   sess.model, bool(args.control))
     max_gap = float(gaps.max()) if len(gaps) else math.inf
     checks = {
         "max_log_gap": {"value": max_gap, "limit": limit},

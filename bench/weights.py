@@ -6,6 +6,16 @@ arrays and neither side makes them. Shapes follow the paper's Table 3
 through the configuration file; the layout is the program's parameter
 tree: ``gnn/b{i}`` (GraphSAGE: ``self{w,b}``, ``neigh{w}``; GCN:
 ``lin{w,b}``) and ``fc/b{i}{w,b}``.
+
+A configuration file may carry a ``"weights"`` block, ``{"last_scale":
+..., "target_offset": ...}``, passed to :func:`make_params` as keyword
+arguments. It calibrates the random weights for the graphs of that
+configuration's traffic, so that their targets lie where a trained
+model's do, as :data:`LAST_SCALE` and :data:`TARGET_OFFSET` do for the
+zoo pool: the static features of a whole LLM graph (thousands of
+``dense`` nodes) are far larger than a zoo graph's, and at the zoo's
+scale they drive targets far out of that range. Without the block the
+weights are those of the two constants.
 """
 from __future__ import annotations
 
@@ -48,11 +58,12 @@ def shapes(model: Dict) -> Dict:
     return {"gnn": gnn, "fc": fc}
 
 
-def make_params(seed: int, model: Dict):
+def make_params(seed: int, model: Dict, *, last_scale: float = LAST_SCALE,
+                target_offset: float = TARGET_OFFSET):
     """Glorot-uniform matrices and small uniform biases, float32, made
     on the default device by one jitted call from ``seed``; the last
-    matrix is scaled by :data:`LAST_SCALE` and the last bias shifted by
-    :data:`TARGET_OFFSET`."""
+    matrix is scaled by ``last_scale`` and the last bias shifted by
+    ``target_offset``."""
     import jax
     import jax.numpy as jnp
     tree = shapes(model)
@@ -70,8 +81,8 @@ def make_params(seed: int, model: Dict):
             out.append(jax.random.uniform(k, shp, jnp.float32, -lim, lim))
         p = jax.tree_util.tree_unflatten(treedef, out)
         last = p["fc"][f"b{model['n_fc_blocks'] - 1}"]
-        last["w"] = last["w"] * LAST_SCALE
-        last["b"] = last["b"] + TARGET_OFFSET
+        last["w"] = last["w"] * last_scale
+        last["b"] = last["b"] + target_offset
         return p
 
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
