@@ -222,7 +222,7 @@ def one_chip(seed: int, hidden: int = 512, train_graphs: int = 32,
         f"recompiles_in_window={info['recompiles_in_window']}")
     log(f"kernels: impl={est.kernel_impl} "
         f"fused_kernel_layers={est.fused_kernel_layers} "
-        f"fused_fallback_layers={est.fused_fallback_layers}")
+        f"fused_segment_layers={est.fused_segment_layers}")
     calls = custom_calls_per_rung(info["engine_obj"], cfg)
     for shape, n in calls.items():
         log(f"tpu_custom_calls rung P={shape[0]} Q={shape[1]} "
@@ -243,7 +243,7 @@ def one_chip(seed: int, hidden: int = 512, train_graphs: int = 32,
     check(st.failed == 0 and st.poisoned == 0, "failed or poisoned requests")
     check(np.isfinite(y).all(), "non-finite predictions")
     check(est.kernel_impl == "pallas", "kernels did not dispatch to Pallas")
-    check(est.fused_fallback_layers == 0, "a fused layer fell back")
+    check(est.fused_segment_layers == 0, "a fused layer left the kernel")
     check(est.fused_kernel_layers == 3 * est.cache_entries,
           "not every compiled shape runs its three fused layers")
     check(all(n == 4 for n in calls.values()),

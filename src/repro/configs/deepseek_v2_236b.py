@@ -4,7 +4,10 @@
 vocab 102400, 160 routed experts top-6 + 2 shared experts; layer 0 uses a
 dense FFN (d_ff 12288) per the released config
 (``first_k_dense_replace=1``). MLA: q_lora 1536, qk_nope 128, qk_rope 64,
-v_head 128.
+v_head 128. Routing as released: softmax scores, ``group_limited_greedy``
+over 8 groups of 20 experts keeping the best 3, gate weights not
+renormalised but scaled by ``routed_scaling_factor`` 16. Not modelled:
+YaRN rope scaling (factor 40), which changes only the rope constants.
 """
 from repro.models.config import ArchConfig, MLAConfig, MoEConfig
 
@@ -20,7 +23,9 @@ CONFIG = ArchConfig(
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=1536,
                   qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
     moe=MoEConfig(n_experts=160, top_k=6, d_expert=1536, n_shared=2,
-                  sharding="ep", first_moe_layer=1, dense_d_ff=12288),
+                  sharding="ep", first_moe_layer=1, dense_d_ff=12288,
+                  n_group=8, topk_group=3, norm_topk_prob=False,
+                  routed_scaling_factor=16.0),
 )
 
 
@@ -32,6 +37,8 @@ def smoke_config() -> ArchConfig:
         mla=MLAConfig(kv_lora_rank=32, q_lora_rank=48,
                       qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16),
         moe=MoEConfig(n_experts=8, top_k=2, d_expert=32, n_shared=1,
-                      sharding="ep", first_moe_layer=1, dense_d_ff=128),
+                      sharding="ep", first_moe_layer=1, dense_d_ff=128,
+                      n_group=4, topk_group=2, norm_topk_prob=False,
+                      routed_scaling_factor=16.0),
         param_dtype="float32",
     )

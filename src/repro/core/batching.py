@@ -230,18 +230,16 @@ def pad_sample(
     y: Optional[np.ndarray] = None,
     meta: Optional[Dict] = None,
     buckets: Sequence[int] = DEFAULT_BUCKETS,
-    truncate_weight: Optional[np.ndarray] = None,
 ) -> GraphSample:
-    """The single padding/truncation path behind every ``GraphSample``.
+    """The single padding path behind every ``GraphSample``.
 
     Pads ``x``/``mask`` to the smallest bucket that fits and keeps the
-    edge list sparse. Graphs larger than the top bucket are truncated to
-    the heaviest nodes by ``truncate_weight`` (default: the last node
-    feature, ``log1p(flops)``) with edges remapped — rare, and the static
-    features still see the whole graph. Shared by
-    :func:`sample_from_graph` (OpGraph path) and
-    ``repro.dataset.builder.records_to_samples`` (dataset path), which
-    previously duplicated this logic.
+    edge list sparse. A graph larger than the top bucket keeps every
+    node, unpadded: the packed layout has no ``[B, N, N]`` term and
+    serves it whole. The bucketed layouts, which do, cut it first with
+    :func:`cut_to_bucket`. Shared by :func:`sample_from_graph` (OpGraph
+    path) and ``repro.dataset.builder.records_to_samples`` (dataset
+    path).
     """
     x = np.asarray(x, dtype=np.float32)
     edges = np.asarray(edges, dtype=np.int32).reshape(-1, 2)
@@ -251,21 +249,7 @@ def pad_sample(
         # segment path (which scatters per edge) numerically identical
         edges = np.unique(edges, axis=0)
     n = x.shape[0]
-    cap = buckets[-1]
-    if n > cap:
-        w = np.asarray(truncate_weight if truncate_weight is not None
-                       else x[:, -1], dtype=np.float64)
-        keep = np.sort(np.argsort(-w, kind="stable")[:cap])
-        remap = -np.ones((n,), dtype=np.int64)
-        remap[keep] = np.arange(cap)
-        x = x[keep]
-        if len(edges):
-            e = edges[(remap[edges[:, 0]] >= 0) & (remap[edges[:, 1]] >= 0)]
-            edges = (np.stack([remap[e[:, 0]], remap[e[:, 1]]], -1)
-                     .astype(np.int32) if len(e)
-                     else np.zeros((0, 2), dtype=np.int32))
-        n = cap
-    size = bucket_for(n, buckets)
+    size = n if n > buckets[-1] else bucket_for(n, buckets)
     xp = np.zeros((size, x.shape[1]), dtype=np.float32)
     xp[:n] = x
     mask = np.zeros((size,), dtype=np.float32)
@@ -278,19 +262,50 @@ def pad_sample(
     )
 
 
+def cut_to_bucket(sample: GraphSample,
+                  buckets: Sequence[int] = DEFAULT_BUCKETS) -> GraphSample:
+    """``sample`` cut to the top bucket, for the layouts that need it.
+
+    The dense and sparse bucketed layouts pad every graph to a node
+    bucket (dense: ``[B, N, N]`` adjacency), so a graph over the top
+    bucket keeps its ``buckets[-1]`` heaviest nodes by the last node
+    feature (``log1p(flops)``), in their order, with the edges among
+    them remapped; its static features still describe the whole graph.
+    A sample that fits is returned as it is. The packed layout never
+    calls this: it serves every node.
+    """
+    cap = buckets[-1]
+    n = sample.n_nodes
+    if n <= cap:
+        return sample
+    keep = np.sort(np.argsort(-sample.x[:n, -1].astype(np.float64),
+                              kind="stable")[:cap])
+    remap = -np.ones((n,), dtype=np.int64)
+    remap[keep] = np.arange(cap)
+    e = sample.edges
+    if len(e):
+        e = e[(remap[e[:, 0]] >= 0) & (remap[e[:, 1]] >= 0)]
+    edges = (np.stack([remap[e[:, 0]], remap[e[:, 1]]], -1).astype(np.int32)
+             if len(e) else np.zeros((0, 2), dtype=np.int32))
+    return GraphSample(x=sample.x[keep], edges=edges,
+                       mask=np.ones((cap,), dtype=np.float32),
+                       static=sample.static, y=sample.y,
+                       meta=dict(sample.meta))
+
+
 def sample_from_graph(
     g: OpGraph,
     y: Optional[np.ndarray] = None,
     buckets: Sequence[int] = DEFAULT_BUCKETS,
     extended_static: bool = False,
 ) -> GraphSample:
-    """Pad one OpGraph into a fixed-size GraphSample (sparse edges)."""
+    """Pad one OpGraph into a GraphSample (sparse edges), every node
+    kept (:func:`pad_sample`)."""
     return pad_sample(
         node_feature_matrix(g),
         np.asarray(g.edges, dtype=np.int32).reshape(-1, 2),
         static_features(g, extended=extended_static),
         y=y, meta=dict(g.meta), buckets=buckets,
-        truncate_weight=np.asarray([nd.flops for nd in g.nodes]),
     )
 
 

@@ -42,11 +42,12 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from .batching import (DEFAULT_BUCKETS, DEFAULT_NODE_BUDGET, GraphSample,
-                       collate_packed, dense_adj, edge_bucket_for,
-                       edge_floor, group_by_bucket, max_batch_for_bucket,
-                       next_pow2, pack_edges, pack_graphs, packed_rung,
-                       packed_rung_ladder, packed_shape,
-                       resolve_packed_budgets, sample_from_graph)
+                       collate_packed, cut_to_bucket, dense_adj,
+                       edge_bucket_for, edge_floor, group_by_bucket,
+                       max_batch_for_bucket, next_pow2, pack_edges,
+                       pack_graphs, packed_rung, packed_rung_ladder,
+                       packed_shape, resolve_packed_budgets,
+                       sample_from_graph)
 from ..kernels import ops as kernel_ops
 from .gnn import (PMGNSConfig, fused_kernel_plan, make_infer_fn,
                   make_staged_packed_infer_fn, packed_staging_layout)
@@ -148,11 +149,15 @@ class EngineStats:
     #: ``"ref"`` (the lax twins; ``repro.kernels.ops``).
     kernel_impl: str = "ref"
     #: Fused message-passing layers, summed over the compiled packed
-    #: shapes, that run the Pallas kernel / that the dispatcher sent to
-    #: the lax reference because their VMEM state does not fit
-    #: (``repro.core.gnn.fused_kernel_plan``).
+    #: shapes, planned onto the Pallas kernel / onto the lax segment
+    #: composition because their VMEM state does not fit
+    #: (``repro.core.gnn.fused_kernel_plan``; per shape in
+    #: :attr:`PredictionEngine.layer_plans`).
     fused_kernel_layers: int = 0
-    fused_fallback_layers: int = 0
+    fused_segment_layers: int = 0
+    #: Packed bins of one graph over a node or edge budget, run whole at
+    #: a power-of-two shape past the rung ladder.
+    lone_bins: int = 0
 
     @property
     def padding_waste_frac(self) -> float:
@@ -248,6 +253,10 @@ class PredictionEngine:
         self._infer = make_infer_fn(cfg)
         self._staged: dict = {}
         self._compiled_shapes: set = set()
+        #: ``(P, Q, G)`` → ``(kernel, segment)``: how many message-passing
+        #: layers of each compiled packed shape run the fused Pallas
+        #: kernel and how many the lax segment composition.
+        self.layer_plans: dict = {}
         #: Guards stats counters + compiled-shape bookkeeping ONLY (not
         #: the jitted call): concurrent submitters — the serving
         #: micro-batcher, replica-pool workers, parallel sweeps — share
@@ -277,9 +286,10 @@ class PredictionEngine:
             if key not in self._staged:
                 self._staged[key] = make_staged_packed_infer_fn(
                     self.cfg, p, q, g)
-                kern, fallback = fused_kernel_plan(self.cfg, p)
+                kern, seg = self.layer_plans[key] = fused_kernel_plan(
+                    self.cfg, p)
                 self.stats.fused_kernel_layers += kern
-                self.stats.fused_fallback_layers += fallback
+                self.stats.fused_segment_layers += seg
             return self._staged[key]
 
     def warmup(self, node_buckets: Optional[Sequence[int]] = None,
@@ -461,13 +471,19 @@ class PredictionEngine:
         return out[:b]
 
     @staticmethod
-    def _apply(fn, args, graphs: int, new_shape: Optional[dict]):
+    def _apply(fn, args, graphs: int, new_shape: Optional[dict],
+               plan: Tuple[int, int] = (0, 0)):
         """Call a jitted apply and fetch its result to the host: under
         ``dippm.compile`` (stats: the shape) when ``new_shape`` is given,
-        the shape's first call, else under ``dippm.run``."""
-        span = (spans.TraceAnnotation(spans.RUN, graphs=graphs)
+        the shape's first call, else under ``dippm.run``. Both carry the
+        shape's layer ``plan`` (kernel and segment layers)."""
+        kern, seg = plan
+        span = (spans.TraceAnnotation(spans.RUN, graphs=graphs,
+                                      kernel_layers=kern, segment_layers=seg)
                 if new_shape is None
-                else spans.TraceAnnotation(spans.COMPILE, **new_shape))
+                else spans.TraceAnnotation(spans.COMPILE, **new_shape,
+                                           kernel_layers=kern,
+                                           segment_layers=seg))
         with span:
             dev = fn(*args)
             with spans.TraceAnnotation(spans.FETCH):
@@ -504,9 +520,10 @@ class PredictionEngine:
         """Run one packed bin; returns ``[len(chunk), n_targets]``.
 
         The bin flattens onto a rung of the engine's ``(P, Q, G)``
-        budget ladder (:func:`~repro.core.batching.packed_shape`); an
-        oversize lone graph escalates its shape. The chunk ships as two
-        flat staging buffers which the jitted apply slices.
+        budget ladder (:func:`~repro.core.batching.packed_shape`); a
+        lone graph over a budget runs whole, its shape escalated to
+        powers of two. The chunk ships as two flat staging buffers which
+        the jitted apply slices.
         """
         nb, eb, gb = self._budgets
         p, q, g = packed_shape(chunk, nb, eb, gb)
@@ -514,13 +531,25 @@ class PredictionEngine:
         with self._lock:
             fresh = ("packed", p, q, g) not in self._compiled_shapes
             fn = self._packed_fn(p, q, g)
+            plan = self.layer_plans[(p, q, g)]
         out = self._apply(fn, (self.params, fbuf, ibuf), len(chunk),
-                          dict(p=p, q=q, g=g) if fresh else None)
+                          dict(p=p, q=q, g=g) if fresh else None, plan)
+        n_real = sum(s.n_nodes for s in chunk)
         with self._lock:
             self.stats.batches_run += 1
             self.stats.node_slots_total += p
-            self.stats.node_slots_real += sum(s.n_nodes for s in chunk)
+            self.stats.node_slots_real += n_real
+            self.stats.lone_bins += len(chunk) == 1 and (
+                n_real > nb or chunk[0].n_edges > eb)
         return out[:len(chunk)]
+
+    def bin_shape(self, chunk: Sequence[GraphSample]) -> Tuple[int, ...]:
+        """The device shape :meth:`run_bin` gives ``chunk``: packed
+        ``(P, Q, G)``, bucketed ``(node bucket, batch bucket)``."""
+        if self.packed:
+            return packed_shape(chunk, *self._budgets)
+        return (min(chunk[0].x.shape[0], self.engine_cfg.buckets[-1]),
+                next_pow2(len(chunk)))
 
     def plan_bins(self, samples: Sequence[GraphSample]) -> List[List[int]]:
         """Split samples into the device bins :meth:`run_bin` accepts.
@@ -537,7 +566,8 @@ class PredictionEngine:
             nb, eb, gb = self._budgets
             return pack_graphs(samples, nb, eb, gb)
         bins: List[List[int]] = []
-        for size, members in sorted(group_by_bucket(samples).items()):
+        cut = [cut_to_bucket(s, self.engine_cfg.buckets) for s in samples]
+        for size, members in sorted(group_by_bucket(cut).items()):
             cap = self._batch_cap(size)
             bins.extend(members[i:i + cap]
                         for i in range(0, len(members), cap))
@@ -556,7 +586,10 @@ class PredictionEngine:
         :class:`~repro.serve.fleet.ReplicaPool`, parallel sweeps)
         genuinely overlap on the device instead of serializing at bin
         granularity. Non-packed bins must be same-bucket
-        (``plan_bins`` guarantees it). Returns
+        (``plan_bins`` guarantees it); a graph over the top bucket is cut
+        to it first (:func:`~repro.core.batching.cut_to_bucket`), since
+        those layouts pad every graph to one. Packed bins serve every
+        node. Returns
         ``[len(chunk), n_targets]`` physical-unit predictions in chunk
         order.
         """
@@ -566,6 +599,8 @@ class PredictionEngine:
         if self.packed:
             out = self._run_packed(chunk)
         else:
+            chunk = [cut_to_bucket(s, self.engine_cfg.buckets)
+                     for s in chunk]
             sizes = {s.x.shape[0] for s in chunk}
             if len(sizes) != 1:
                 raise ValueError(

@@ -425,18 +425,22 @@ def _fused_mp_stack(p: Params, cfg: PMGNSConfig, x, mask, edges, edge_mask):
     edge-softmax, then the fused gather⊙attention→scatter stage.
     Numerics match the composed path to float tolerance
     (``benchmarks/fused_mp.py`` gates ≤ 1e-5).
+
+    Each layer takes the path :func:`fused_layer_impls` plans for the
+    bin's ``P``: with ``use_pallas``, the fused kernel where its VMEM
+    state fits; else the lax segment composition.
     """
-    if cfg.use_pallas:
-        from ..kernels.ops import fused_mp_layer as fused
-    else:
-        from ..kernels.ref import fused_mp_layer_ref as fused
+    from ..kernels.ops import fused_mp_layer
+    impls = fused_layer_impls(cfg, x.shape[0])
     h = x
     if cfg.variant == "graphsage":
         for i in range(cfg.n_gnn_blocks):
             lp = p["gnn"][f"b{i}"]
-            h = fused(h, edges, edge_mask, mask, w_neigh=lp["neigh"]["w"],
-                      w_self=lp["self"]["w"], bias=lp["self"].get("b"),
-                      mode="mean", combine="split", act="relu")
+            h = fused_mp_layer(h, edges, edge_mask, mask,
+                               w_neigh=lp["neigh"]["w"],
+                               w_self=lp["self"]["w"],
+                               bias=lp["self"].get("b"), mode="mean",
+                               combine="split", act="relu", impl=impls[i])
     elif cfg.variant == "gcn":
         from ..kernels.ref import segment_degree_ref
         n = x.shape[0]
@@ -449,24 +453,21 @@ def _fused_mp_stack(p: Params, cfg: PMGNSConfig, x, mask, edges, edge_mask):
         ss = dinv * dinv * mask
         for i in range(cfg.n_gnn_blocks):
             lp = p["gnn"][f"b{i}"]["lin"]
-            h = fused(h, edges, w, mask, w_neigh=lp["w"],
-                      bias=lp.get("b"), mode="sum", combine="pre",
-                      self_scale=ss, act="relu")
+            h = fused_mp_layer(h, edges, w, mask, w_neigh=lp["w"],
+                               bias=lp.get("b"), mode="sum", combine="pre",
+                               self_scale=ss, act="relu", impl=impls[i])
     elif cfg.variant == "gin":
         for i in range(cfg.n_gnn_blocks):
             lp = p["gnn"][f"b{i}"]
             m0, m1 = lp["mlp"]["l0"], lp["mlp"]["l1"]
-            r = fused(h, edges, edge_mask, None, w_neigh=m0["w"],
-                      bias=m0.get("b"), mode="sum", combine="pre",
-                      self_scale=1.0 + lp["eps"], act="relu")
+            r = fused_mp_layer(h, edges, edge_mask, None, w_neigh=m0["w"],
+                               bias=m0.get("b"), mode="sum", combine="pre",
+                               self_scale=1.0 + lp["eps"], act="relu",
+                               impl=impls[i])
             h = jax.nn.relu((r @ m1["w"] + m1["b"]) * mask[:, None])
     elif cfg.variant == "gat":
-        if cfg.use_pallas:
-            from ..kernels.ops import edge_softmax, fused_gat_aggregate
-        else:
-            from ..kernels.ref import (
-                edge_softmax_ref as edge_softmax,
-                fused_gat_aggregate_ref as fused_gat_aggregate)
+        from ..kernels.ops import edge_softmax, fused_gat_aggregate
+        softmax_impl = None if cfg.use_pallas else "ref"
         n = x.shape[0]
         src, dst = edges[:, 0], edges[:, 1]
         for i in range(cfg.n_gnn_blocks):
@@ -479,9 +480,10 @@ def _fused_mp_stack(p: Params, cfg: PMGNSConfig, x, mask, edges, edge_mask):
             s = jax.nn.leaky_relu(
                 jnp.take(ed, dst, axis=0) + jnp.take(es, src, axis=0),
                 0.2)                                    # [Q, heads]
-            att = edge_softmax(s[None], dst[None], edge_mask[None], n)[0]
-            h = jax.nn.relu(
-                fused_gat_aggregate(z, edges, edge_mask, att, mask))
+            att = edge_softmax(s[None], dst[None], edge_mask[None], n,
+                               impl=softmax_impl)[0]
+            h = jax.nn.relu(fused_gat_aggregate(z, edges, edge_mask, att,
+                                                mask, impl=impls[i]))
     else:                                               # "mlp" baseline
         for i in range(cfg.n_gnn_blocks):
             lp = p["gnn"][f"b{i}"]
@@ -489,26 +491,39 @@ def _fused_mp_stack(p: Params, cfg: PMGNSConfig, x, mask, edges, edge_mask):
     return h
 
 
-def fused_kernel_plan(cfg: PMGNSConfig, p: int) -> Tuple[int, int]:
-    """``(kernel, fallback)`` fused layers for a packed bin of ``p`` nodes.
-
-    Mirrors the dispatch of :func:`_fused_mp_stack`: how many message-
-    passing layers run the fused Pallas kernel, and how many the
-    dispatcher sends to the lax reference because their resident state
-    does not fit VMEM (:func:`repro.kernels.ops.fused_fits`). ``(0, 0)``
-    when inference does not dispatch to Pallas at all (``use_pallas``
-    off, no fused stack, the MLP baseline, or a non-TPU backend).
-    """
+def fused_layer_impls(cfg: PMGNSConfig, p: int) -> Tuple[str, ...]:
+    """The path of each message-passing layer of a packed bin of ``p``
+    nodes, as :func:`_fused_mp_stack` runs it: ``"pallas"`` (the fused
+    kernel) where the layer's resident state fits VMEM
+    (:func:`repro.kernels.ops.fused_fits`), ``"ref"`` (the lax segment
+    composition) where it does not, and ``"ref"`` throughout when
+    inference does not dispatch to Pallas (``use_pallas`` off, the MLP
+    baseline, or a non-TPU backend). A whole graph past the fused
+    kernel's reach is planned here, so the dispatcher never warns."""
     from ..kernels.ops import fused_fits, kernel_impl
-    if (not cfg.use_pallas or not cfg.resolved_fused or cfg.variant == "mlp"
+    if (not cfg.use_pallas or cfg.variant == "mlp"
             or kernel_impl() != "pallas"):
-        return 0, 0
+        return ("ref",) * cfg.n_gnn_blocks
     mode = "mean" if cfg.variant == "graphsage" else "sum"
     # GAT's fused stage aggregates the [P, hidden] projection
     f_in = cfg.hidden if cfg.variant == "gat" else cfg.node_feat_dim
     dims = [f_in] + [cfg.hidden] * (cfg.n_gnn_blocks - 1)
-    fits = sum(fused_fits(p, f, cfg.hidden, mode) for f in dims)
-    return fits, len(dims) - fits
+    return tuple("pallas" if fused_fits(p, f, cfg.hidden, mode) else "ref"
+                 for f in dims)
+
+
+def fused_kernel_plan(cfg: PMGNSConfig, p: int) -> Tuple[int, int]:
+    """``(kernel, segment)`` fused layers for a packed bin of ``p`` nodes:
+    how many of :func:`fused_layer_impls` run the fused Pallas kernel
+    and how many the lax segment composition. ``(0, 0)`` when inference
+    does not dispatch to Pallas at all (``use_pallas`` off, no fused
+    stack, the MLP baseline, or a non-TPU backend)."""
+    from ..kernels.ops import kernel_impl
+    if (not cfg.use_pallas or not cfg.resolved_fused or cfg.variant == "mlp"
+            or kernel_impl() != "pallas"):
+        return 0, 0
+    kern = fused_layer_impls(cfg, p).count("pallas")
+    return kern, cfg.n_gnn_blocks - kern
 
 
 def pmgns_apply(p: Params, cfg: PMGNSConfig, batch: Dict[str, jnp.ndarray],

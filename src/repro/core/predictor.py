@@ -45,6 +45,13 @@ class Prediction:
     ``latency_ms`` / ``energy_j`` / ``memory_mb`` are the PMGNS regression
     targets in physical units; ``mig`` / ``tpu_slice`` / ``pods`` are the
     §3.5 resource recommendations derived from the predicted memory.
+    ``meta`` is the graph's own metadata. ``serving`` is how the
+    service's engine ran it: ``nodes`` and ``edges`` served, the device
+    shape ``bin`` of its bin (packed ``(P, Q, G)``) and
+    ``queue_wait_ms`` from enqueue to the batcher's drain; ``None`` for
+    cache hits and coalesced duplicates, which no bin ran, and outside
+    the service. It is a record of one serving, so two predictions of
+    the same graph compare equal whatever it says.
     """
 
     latency_ms: float
@@ -54,6 +61,8 @@ class Prediction:
     tpu_slice: Optional[str]
     pods: int
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    serving: Optional[Dict[str, Any]] = dataclasses.field(
+        default=None, compare=False)
 
     def __repr__(self) -> str:  # pragma: no cover — cosmetic
         return (f"Prediction(latency={self.latency_ms:.3f} ms, "

@@ -19,11 +19,12 @@ __all__ = ["TraceAnnotation", "SUBMIT", "PARSE", "FINGERPRINT",
 
 #: One request in ``submit``/``submit_json``, caller's thread (``req``).
 SUBMIT = "dippm.submit"
-#: ``frontends.from_json`` of the request's document.
+#: ``frontends.from_json`` of the request's document (``nodes``).
 PARSE = "dippm.parse"
-#: ``OpGraph.fingerprint`` (cache and quarantine key).
+#: ``OpGraph.fingerprint`` (cache and quarantine key; ``nodes``).
 FINGERPRINT = "dippm.fingerprint"
-#: ``sample_from_graph``: node features, static features, padding.
+#: ``sample_from_graph``: node features, static features, padding
+#: (``nodes``).
 FEATURISE = "dippm.featurise"
 #: ``RequestQueue.put``/``put_many``, queue lock included.
 ENQUEUE = "dippm.enqueue"
@@ -36,11 +37,13 @@ DRAIN = "dippm.drain"
 PLAN = "dippm.plan"
 #: Host staging of one bin (``graphs``; packed ``p``, ``q``, ``g``).
 STAGE = "dippm.stage"
-#: One bin's device call through its result on the host (``graphs``).
+#: One bin's device call through its result on the host (``graphs``;
+#: the shape's layer plan, ``kernel_layers`` and ``segment_layers``).
 RUN = "dippm.run"
 #: The device-to-host copy of a bin's result, blocking on the device.
 FETCH = "dippm.fetch"
-#: In place of ``dippm.run``: the first call of a shape, which compiles.
+#: In place of ``dippm.run``: the first call of a shape, which compiles
+#: (the shape; ``kernel_layers``, ``segment_layers``).
 COMPILE = "dippm.compile"
 #: Decode and resolve a drain's futures and their callbacks
 #: (``requests``).

@@ -28,7 +28,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.batching import DEFAULT_BUCKETS, GraphSample, pad_sample
+from ..core.batching import (DEFAULT_BUCKETS, GraphSample, cut_to_bucket,
+                             pad_sample)
 from ..core.node_features import NODE_FEATURE_DIM, node_feature_matrix
 from ..core.static_features import static_features
 from ..perfmodel.cost_model import estimate
@@ -295,10 +296,14 @@ def records_to_samples(records: Sequence[DatasetRecord],
 
     Samples keep the edge list sparse; the dense ``[B, N, N]`` adjacency
     only exists inside ``repro.core.batching.collate`` (per batch), so a
-    paper-scale dataset stays O(nodes + edges) on the host.
+    paper-scale dataset stays O(nodes + edges) on the host. Training
+    batches by node bucket, so a record over the top bucket is cut to it
+    (:func:`~repro.core.batching.cut_to_bucket`).
     """
-    return [pad_sample(r.x, r.edges, r.static, y=r.y,
-                       meta={"family": r.family, **r.meta}, buckets=buckets)
+    return [cut_to_bucket(
+                pad_sample(r.x, r.edges, r.static, y=r.y,
+                           meta={"family": r.family, **r.meta},
+                           buckets=buckets), buckets)
             for r in records]
 
 

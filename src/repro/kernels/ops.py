@@ -11,9 +11,11 @@ check the kernels on a CPU (they steer :func:`kernel_impl` with
 The fused message-passing kernels keep a whole ``[P, F]`` block resident
 in VMEM. A shape whose estimated working set does not fit the kernel's
 scoped VMEM limit (:func:`fused_fits`) runs the reference composition
-instead, and says so: the dispatcher warns at trace time, and the
-prediction engine counts such layers per compiled shape
-(``EngineStats.fused_fallback_layers``).
+instead. PMGNS plans that per layer (``repro.core.gnn.fused_layer_impls``)
+and passes ``impl`` explicitly, and the prediction engine counts each
+compiled shape's layers on either path (``EngineStats.fused_kernel_layers``,
+``fused_segment_layers``); a caller that leaves ``impl`` to the dispatcher
+on such a shape gets a trace-time warning.
 """
 from __future__ import annotations
 

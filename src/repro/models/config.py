@@ -24,6 +24,16 @@ class MoEConfig:
     first_moe_layer: int = 0
     #: dense-FFN hidden dim for pre-MoE layers (deepseek layer 0)
     dense_d_ff: int = 0
+    #: Group-limited greedy routing (deepseek-v2 ``group_limited_greedy``):
+    #: the experts fall into ``n_group`` equal groups, a token keeps the
+    #: ``topk_group`` groups whose best expert scores highest and picks its
+    #: ``top_k`` experts among them. One group is plain greedy top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    #: Renormalise the top-k gate weights to sum to one; otherwise they
+    #: keep their softmax scores, times ``routed_scaling_factor``.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)

@@ -504,11 +504,26 @@ def moe_init(key, cfg: ArchConfig) -> Params:
 
 
 def _route(router_w, x_flat, mo: MoEConfig):
-    """→ (probs [T,k], ids [T,k], aux_loss)."""
+    """→ (probs [T,k], ids [T,k], aux_loss).
+
+    Softmax scores; with ``n_group > 1`` only the experts of each token's
+    ``topk_group`` best groups (a group ranks by its best expert) stay
+    eligible. The top-k gate weights are renormalised
+    (``norm_topk_prob``) or scaled by ``routed_scaling_factor``."""
     logits = (x_flat.astype(jnp.float32) @ router_w)
     probs_all = jax.nn.softmax(logits, axis=-1)
-    probs, ids = lax.top_k(probs_all, mo.top_k)
-    probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)
+    scores = probs_all
+    if mo.n_group > 1:
+        grouped = scores.reshape(scores.shape[0], mo.n_group, -1)
+        _, top_groups = lax.top_k(grouped.max(axis=-1), mo.topk_group)
+        keep = jax.nn.one_hot(top_groups, mo.n_group,
+                              dtype=scores.dtype).sum(axis=1)
+        scores = (grouped * keep[:, :, None]).reshape(scores.shape)
+    probs, ids = lax.top_k(scores, mo.top_k)
+    if mo.norm_topk_prob:
+        probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)
+    else:
+        probs = probs * mo.routed_scaling_factor
     # load-balancing aux loss (Switch-style)
     me = probs_all.mean(axis=0)
     ce = jnp.zeros((mo.n_experts,), jnp.float32).at[ids.reshape(-1)].add(

@@ -142,6 +142,10 @@ class ReplicaPool:
         more devices."""
         return self.replicas[0].plan_bins(samples)
 
+    def bin_shape(self, chunk: Sequence[GraphSample]) -> Tuple[int, ...]:
+        """The device shape every replica gives ``chunk``."""
+        return self.replicas[0].bin_shape(chunk)
+
     def warmup(self, *a, **kw) -> int:
         """Warm every replica's compiled-fn ladder (same signature as
         ``PredictionEngine.warmup``; each replica holds its own jit
@@ -349,7 +353,8 @@ class ReplicaPool:
             agg.node_slots_total += s.node_slots_total
             agg.node_slots_real += s.node_slots_real
             agg.fused_kernel_layers += s.fused_kernel_layers
-            agg.fused_fallback_layers += s.fused_fallback_layers
+            agg.fused_segment_layers += s.fused_segment_layers
+            agg.lone_bins += s.lone_bins
             if s.bf16_max_abs_delta is not None:
                 deltas.append(s.bf16_max_abs_delta)
         agg.precision = self.replicas[0].stats.precision

@@ -369,7 +369,8 @@ class PredictionService:
         fp = None
         flight = None
         if self._cache is not None or self._quarantine is not None:
-            with spans.TraceAnnotation(spans.FINGERPRINT):
+            with spans.TraceAnnotation(spans.FINGERPRINT,
+                                       nodes=g.num_nodes):
                 fp = g.fingerprint()
             fut = self._quarantine_fastfail(fp)
             if fut is not None:
@@ -388,7 +389,7 @@ class PredictionService:
                     self._resolve_waiter(waiter, y)
                 return fut
         ecfg = self.engine.engine_cfg
-        with spans.TraceAnnotation(spans.FEATURISE):
+        with spans.TraceAnnotation(spans.FEATURISE, nodes=g.num_nodes):
             sample = sample_from_graph(g, buckets=ecfg.buckets,
                                        extended_static=ecfg.extended_static)
         return self._submit_sample(sample, meta, fp, flight, deadline,
@@ -411,8 +412,9 @@ class PredictionService:
         req_id = next(self._req_ids)
         with spans.TraceAnnotation(spans.SUBMIT, req=req_id):
             try:
-                with spans.TraceAnnotation(spans.PARSE):
+                with spans.TraceAnnotation(spans.PARSE) as parse:
                     g = from_json(doc)
+                    parse.set_metadata(nodes=g.num_nodes)
             except GraphValidationError as e:
                 fut = PredictionFuture()
                 fut._reject(e)
@@ -483,7 +485,7 @@ class PredictionService:
         deadline = self._deadline_at(deadline_ms)
 
         def _featurize(g):
-            with spans.TraceAnnotation(spans.FEATURISE):
+            with spans.TraceAnnotation(spans.FEATURISE, nodes=g.num_nodes):
                 return sample_from_graph(
                     g, buckets=ecfg.buckets,
                     extended_static=ecfg.extended_static)
@@ -500,7 +502,8 @@ class PredictionService:
             meta = dict(g.meta)
             fp = None
             if self._cache is not None or self._quarantine is not None:
-                with spans.TraceAnnotation(spans.FINGERPRINT):
+                with spans.TraceAnnotation(spans.FINGERPRINT,
+                                           nodes=g.num_nodes):
                     fp = g.fingerprint()
                 fut = self._quarantine_fastfail(fp)
                 if fut is not None:
@@ -914,7 +917,12 @@ class PredictionService:
 
     def _process(self, batch: List[Request], span) -> None:
         """Run one drained batch and settle every future in it; ``span``
-        (the drain's trace span) gets the batch's total queue wait."""
+        (the drain's trace span) gets the batch's total queue wait.
+
+        Each prediction the engine served carries its serving record
+        (``Prediction.serving``): the nodes and edges it ran with, the
+        device shape of its bin (:meth:`PredictionEngine.bin_shape`) and
+        its queue wait in ms, from enqueue to this drain."""
         from ..core.predictor import make_prediction
         lats: List[float] = []
         done = failed = n_bins = 0
@@ -922,14 +930,16 @@ class PredictionService:
             # deadline sweep at drain time: requests that expired while
             # queued never cost a bin slot
             now = time.perf_counter()
-            span.set_metadata(queue_wait_ms=1e3 * sum(
-                now - r.t_submit for r in batch))
+            waits = [1e3 * (now - r.t_submit) for r in batch]
+            span.set_metadata(queue_wait_ms=sum(waits))
             live: List[Request] = []
-            for r in batch:
+            live_wait: List[float] = []
+            for r, wait in zip(batch, waits):
                 if r.expired(now):
                     self._expire_request(r)
                 else:
                     live.append(r)
+                    live_wait.append(wait)
             if not live:
                 return
             samples = [r.sample for r in live]
@@ -948,6 +958,7 @@ class PredictionService:
             # fleet has already exhausted requeue-on-healthy-replicas
             # by the time an error surfaces here)
             bin_err: List[Optional[BaseException]] = [None] * len(samples)
+            bin_of: Dict[int, Tuple[int, ...]] = {}   # rider → bin shape
             if self._fleet and n_bins > 1:
                 # fleet backend: fan this drain's bins out so they run
                 # on the replicas concurrently
@@ -955,8 +966,11 @@ class PredictionService:
                 for idx in bins:
                     keep, bin_dl = self._prune_bin(idx, live, bin_err)
                     if keep:
+                        chunk = [samples[j] for j in keep]
+                        bin_of.update(dict.fromkeys(
+                            keep, self.engine.bin_shape(chunk)))
                         futs.append((keep, bin_dl, self.engine.submit_bin(
-                            [samples[j] for j in keep], bin_dl)))
+                            chunk, bin_dl)))
                 for keep, bin_dl, f in futs:
                     try:
                         ys[keep] = f.result()
@@ -968,9 +982,11 @@ class PredictionService:
                     keep, bin_dl = self._prune_bin(idx, live, bin_err)
                     if not keep:
                         continue
+                    chunk = [samples[j] for j in keep]
+                    bin_of.update(dict.fromkeys(
+                        keep, self.engine.bin_shape(chunk)))
                     try:
-                        ys[keep] = self._run_bin_sync(
-                            [samples[j] for j in keep], bin_dl)
+                        ys[keep] = self._run_bin_sync(chunk, bin_dl)
                     except Exception as e:
                         self._recover_chunk(keep, samples, ys, bin_err,
                                             bin_dl, e, live)
@@ -994,6 +1010,10 @@ class PredictionService:
                         self._fail_request(r, e)
                         failed += 1
                         continue
+                    s = r.sample
+                    pred.serving = {
+                        "nodes": s.n_nodes, "edges": s.n_edges,
+                        "bin": bin_of[j], "queue_wait_ms": live_wait[j]}
                     lats.append(lat_ms)
                     done += 1
                     r.future._resolve(pred, lat_ms)

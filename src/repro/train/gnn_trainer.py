@@ -57,8 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.batching import (GraphSample, batches_by_bucket, collate,
-                             collate_packed, next_pow2, pack_graphs,
-                             stack_epoch_segments)
+                             collate_packed, cut_to_bucket, next_pow2,
+                             pack_graphs, stack_epoch_segments)
 from ..core.gnn import (PMGNSConfig, decode_targets, encode_targets, huber,
                         mape, pmgns_apply, pmgns_init)
 from ..checkpoint import CheckpointManager, latest_step, restore_checkpoint
@@ -166,9 +166,10 @@ def evaluate(params, cfg: PMGNSConfig, samples: Sequence[GraphSample],
     Batch layout follows ``cfg.resolved_layout`` — sparse eval batches
     carry padded edge lists and never densify the adjacency; packed eval
     bin-packs mixed-size graphs onto one flat node axis and masks the
-    padded graph slots out of every metric.
+    padded graph slots out of every metric. A graph over the top bucket
+    is cut to it, as in training.
     """
-    samples = list(samples)
+    samples = [cut_to_bucket(s) for s in samples]
     layout = cfg.resolved_layout
     if layout == "packed":
         batches = _eval_packed_batches(samples, batch_size)
@@ -313,6 +314,10 @@ def train_pmgns(
     continues from the next epoch, bit-matching an uninterrupted run. If
     the directory has no committed checkpoint, training starts fresh —
     so a relaunch loop can always pass ``resume_from=checkpoint_dir``.
+
+    Training batches by node bucket in every layout, so a sample over
+    the top bucket is cut to it first
+    (:func:`~repro.core.batching.cut_to_bucket`).
     """
     if cfg.mode not in ("scan", "eager"):
         raise ValueError(f"TrainConfig.mode must be 'scan' or 'eager', "
@@ -323,7 +328,8 @@ def train_pmgns(
             "data_parallel=True shards the scan's batch axis, but packed "
             "segments have no batch axis to shard (one flat node axis per "
             "step) — train data-parallel with layout='sparse' instead")
-    train_samples = list(train_samples)
+    train_samples = [cut_to_bucket(s) for s in train_samples]
+    val_samples = [cut_to_bucket(s) for s in val_samples]
     key = jax.random.PRNGKey(cfg.seed)
     key, init_key = jax.random.split(key)
     params = pmgns_init(init_key, model_cfg)

@@ -200,7 +200,7 @@ def test_fused_training_uses_composed_path():
 
 def test_engine_counts_fused_dispatch(monkeypatch):
     """Every compiled packed shape adds its fused layers to EngineStats,
-    split into kernel and fallback by the same VMEM guard the
+    split into kernel and segment path by the same VMEM guard the
     dispatcher applies; off a TPU nothing dispatches to Pallas."""
     from repro.core.engine import PredictionEngine
     from repro.core.gnn import fused_kernel_plan
@@ -218,4 +218,6 @@ def test_engine_counts_fused_dispatch(monkeypatch):
     eng._packed_fn(512, 832, 32)
     eng._packed_fn(512, 832, 32)                 # cached: not counted again
     assert (eng.stats.fused_kernel_layers,
-            eng.stats.fused_fallback_layers) == (3, 3)
+            eng.stats.fused_segment_layers) == (3, 3)
+    assert eng.layer_plans == {(256, 416, 16): (3, 0),
+                               (512, 832, 32): (0, 3)}

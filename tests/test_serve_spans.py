@@ -118,12 +118,42 @@ def test_first_call_of_a_shape_is_a_compile_span(layout, tmp_path):
     assert names == [span_names.STAGE, span_names.COMPILE, span_names.FETCH,
                      span_names.STAGE, span_names.RUN, span_names.FETCH]
     stage, compile_, run = events[0], events[1], events[4]
-    assert stage[4]["graphs"] == 2 and run[4] == {"graphs": 2}
+    plan = {"kernel_layers": 0, "segment_layers": 0}    # no Pallas here
+    assert stage[4]["graphs"] == 2 and run[4] == {"graphs": 2, **plan}
+    assert {k: compile_[4][k] for k in plan} == plan
     if layout == "packed":
-        assert set(stage[4]) == set(compile_[4]) | {"graphs"} == \
-            {"graphs", "p", "q", "g"}
+        assert set(stage[4]) == set(compile_[4]) - set(plan) | {"graphs"} \
+            == {"graphs", "p", "q", "g"}
     else:
-        assert set(compile_[4]) == {"nodes", "edges", "batch"}
+        assert set(compile_[4]) - set(plan) == {"nodes", "edges", "batch"}
     fetch = events[5]
     assert run[2] <= fetch[2] and \
         fetch[2] + fetch[3] <= run[2] + run[3]     # fetch inside run
+
+
+def test_submit_spans_carry_nodes(packed_dippm, tmp_path):
+    _, events = traced(lambda: _serve_all(packed_dippm), tmp_path)
+    sizes = [g.num_nodes for g in GRAPHS]
+    for name, want in ((span_names.PARSE, sizes),
+                       (span_names.FINGERPRINT, sizes + sizes[:3]),
+                       (span_names.FEATURISE, sizes + sizes[:3])):
+        assert [e[4]["nodes"] for e in events if e[0] == name] == want
+
+
+def test_predictions_carry_their_serving_record(packed_dippm):
+    with packed_dippm.serve(max_wait_ms=60_000.0) as svc:
+        futs = [svc.submit_json(g.to_json()) for g in GRAPHS]
+        svc.flush()
+        preds = [f.result(60) for f in futs]
+        hit = svc.submit(GRAPHS[0]).result(60)         # from the cache
+        samples = [sample_from_graph(g) for g in GRAPHS]
+        bins = {i: tuple(svc.engine.bin_shape([samples[j] for j in b]))
+                for b in svc.engine.plan_bins(samples) for i in b}
+    for i, (g, pred) in enumerate(zip(GRAPHS, preds)):
+        served = pred.serving
+        assert served["nodes"] == g.num_nodes
+        assert served["edges"] == len(set(g.edges))
+        assert served["bin"] == bins[i]
+        assert served["queue_wait_ms"] >= 0
+        assert "serving" not in pred.meta
+    assert hit.serving is None and hit == preds[0]

@@ -111,3 +111,21 @@ def test_staged_packed_apply_top_rung(one_chip, monkeypatch, variant):
                     _spec(one_chip, (i_len,), jnp.int32)
                     ).compile().as_text()
     assert text.count("tpu_custom_call") == STAGED_CUSTOM_CALLS[variant]
+
+
+def test_staged_packed_apply_lone_bin(one_chip, monkeypatch):
+    """A whole LLM prefill graph's lone bin (P = Q = 16,384): GraphSAGE's
+    first layer runs the fused kernel, the two others the segment
+    composition, and Mosaic takes the kernel at that size."""
+    monkeypatch.setattr(ops, "kernel_impl", lambda: "pallas")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = PMGNSConfig(layout="packed", use_pallas=True)
+    p, q, g = 16384, 16384, 256
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda k: pmgns_init(k, cfg), jax.random.PRNGKey(0)))
+    _, _, _, f_len, i_len = packed_staging_layout(cfg, p, q, g)
+    text = make_staged_packed_infer_fn(cfg, p, q, g).lower(
+        params, _spec(one_chip, (f_len,)),
+        _spec(one_chip, (i_len,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2     # one layer + the readout
