@@ -49,9 +49,10 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .ir import OpGraph
-from .node_features import NODE_FEATURE_DIM, node_feature_matrix
-from .static_features import static_features
+from .ir import GraphArrays, OpGraph
+from .node_features import (NODE_FEATURE_DIM, columns_feature_matrix,
+                            graph_columns)
+from .static_features import columns_static_features
 
 DEFAULT_BUCKETS: Tuple[int, ...] = (32, 64, 128, 256, 512, 1024)
 
@@ -223,6 +224,15 @@ def group_by_bucket(
     return by_bucket
 
 
+def _rows_increase(edges: np.ndarray) -> bool:
+    """Whether ``[E, 2]`` rows strictly increase in lexicographic order,
+    so that they are already what ``np.unique(edges, axis=0)`` returns
+    (``filter_and_preprocess`` emits them so): a check with no sort."""
+    s, d = edges[:, 0], edges[:, 1]
+    return bool(((s[1:] > s[:-1])
+                 | ((s[1:] == s[:-1]) & (d[1:] > d[:-1]))).all())
+
+
 def pad_sample(
     x: np.ndarray,
     edges: np.ndarray,
@@ -242,8 +252,8 @@ def pad_sample(
     path).
     """
     x = np.asarray(x, dtype=np.float32)
-    edges = np.asarray(edges, dtype=np.int32).reshape(-1, 2)
-    if len(edges):
+    edges = np.array(edges, dtype=np.int32).reshape(-1, 2)
+    if not _rows_increase(edges):
         # canonicalize: unique rows, sorted — dense_adj collapses
         # duplicates by assignment, so dedup here keeps the sparse
         # segment path (which scatters per edge) numerically identical
@@ -294,17 +304,18 @@ def cut_to_bucket(sample: GraphSample,
 
 
 def sample_from_graph(
-    g: OpGraph,
+    g: OpGraph | GraphArrays,
     y: Optional[np.ndarray] = None,
     buckets: Sequence[int] = DEFAULT_BUCKETS,
     extended_static: bool = False,
 ) -> GraphSample:
-    """Pad one OpGraph into a GraphSample (sparse edges), every node
-    kept (:func:`pad_sample`)."""
+    """Pad one graph into a GraphSample (sparse edges), every node kept
+    (:func:`pad_sample`). ``g`` is an :class:`~repro.core.ir.OpGraph` or
+    the :class:`~repro.core.ir.GraphArrays` of a parsed document."""
+    cols = graph_columns(g)
     return pad_sample(
-        node_feature_matrix(g),
-        np.asarray(g.edges, dtype=np.int32).reshape(-1, 2),
-        static_features(g, extended=extended_static),
+        columns_feature_matrix(cols), g.edges,
+        columns_static_features(cols, g.meta, extended=extended_static),
         y=y, meta=dict(g.meta), buckets=buckets,
     )
 

@@ -19,11 +19,16 @@ paper's Fig. 2.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
+from operator import itemgetter
 from typing import Any, Dict, Optional
 
-from .ir import (OP_INDEX, GraphValidationError, OpGraph, OpNode,
-                 filter_and_preprocess)
+import numpy as np
+
+from .ir import (OP_INDEX, GraphArrays, GraphValidationError, OpGraph,
+                 OpNode, dtype_bytes, filter_and_preprocess)
+from .node_features import NodeColumns, attr_matrix
 from .tracer import trace_graph
 
 #: aliases accepted from external exporters → canonical OP_VOCAB names
@@ -182,6 +187,109 @@ def from_json(doc: Dict[str, Any]) -> OpGraph:
     edges = _validated_edges(doc, seen_ids)
     return _check_acyclic(
         filter_and_preprocess(nodes, edges, meta=doc.get("meta", {})))
+
+
+_ID = itemgetter("id")
+_OP = itemgetter("op")
+_OUT_SHAPE = itemgetter("out_shape")
+#: What ``from_json_arrays`` cannot read as ``from_json`` does: a
+#: missing or mistyped field. ``from_json`` then parses the document.
+_UNREADABLE = (AttributeError, IndexError, KeyError, OverflowError,
+               TypeError, ValueError)
+
+
+def _canonical_op(op: str) -> str:
+    """``from_json``'s op name for an exporter's ``op``."""
+    if op in OP_INDEX:
+        return op
+    return _OP_ALIASES.get(op.lower(), "elementwise")
+
+
+def _field(nodes: list, key: str, default: Any) -> list:
+    return list(map(dict.get, nodes, itertools.repeat(key),
+                    itertools.repeat(default)))
+
+
+def _floats(nodes: list, key: str) -> np.ndarray:
+    """The float field ``key`` (0.0 where missing) of every node."""
+    return np.fromiter(map(dict.get, nodes, itertools.repeat(key),
+                           itertools.repeat(0.0)), np.float64, len(nodes))
+
+
+def from_json_arrays(doc: Any) -> Optional[GraphArrays]:
+    """Parse a canonical ``repro.opgraph.v1`` document straight into
+    arrays.
+
+    Returns the :class:`~repro.core.ir.GraphArrays` of the graph that
+    :func:`from_json` would build — equal node columns, edges, meta and
+    fingerprint — without an ``OpNode`` per node. Each field is read in
+    one pass over the node dicts; ``attrs`` only where it is non-empty.
+
+    It takes a document already in the form ``filter_and_preprocess``
+    gives, as ``OpGraph.to_json`` writes it, which three checks with no
+    sort confirm: ids ``0..n-1`` in list order, every edge from a lower
+    to a higher id (so acyclic, with no self-loop or dangling end), and
+    edge rows strictly increasing (so sorted and unique).
+
+    Returns ``None`` for any other document: another schema or a raw
+    exporter node list, one that fails a check, a negative dim, a
+    missing or mistyped field, a non-finite number, a non-string op or
+    dtype, a dim that is not an int. :func:`from_json` then parses it,
+    canonicalises it, or raises the
+    :class:`~repro.core.ir.GraphValidationError` that names its fault."""
+    if not isinstance(doc, dict) or doc.get("schema") != "repro.opgraph.v1":
+        return None
+    try:
+        nodes = doc["nodes"]
+        n = len(nodes)
+        if not (np.fromiter(map(_ID, nodes), np.int64, n)
+                == np.arange(n)).all():
+            return None
+        edge_list = doc["edges"]
+        m = len(edge_list)
+        pairs = np.fromiter(map(len, edge_list), np.int64, m)
+        ends = np.fromiter(itertools.chain.from_iterable(edge_list),
+                           np.int64, 2 * m).reshape(m, 2)
+        src, dst = ends[:, 0], ends[:, 1]
+        key = src * n + dst
+        if not ((pairs == 2).all() and (src >= 0).all() and (src < dst).all()
+                and (dst < n).all() and (key[1:] > key[:-1]).all()):
+            return None
+        raw_ops = list(map(_OP, nodes))
+        shapes = list(map(_OUT_SHAPE, nodes))
+        dtypes = _field(nodes, "dtype", "float32")
+        attrs = _field(nodes, "attrs", {})
+        flops, macs, param_bytes = (_floats(nodes, "flops"),
+                                    _floats(nodes, "macs"),
+                                    _floats(nodes, "param_bytes"))
+        finite = np.isfinite(_floats(nodes, "bytes_accessed")).all()
+        rank = np.fromiter(map(len, shapes), np.int64, n)
+        flat = list(itertools.chain.from_iterable(shapes))
+        dims = np.fromiter(flat, np.int64, len(flat))
+        meta = dict(doc.get("meta", {}))
+        op_names = set(raw_ops)
+        width = {dt: dtype_bytes(dt) for dt in set(dtypes)}
+        if not (all(type(op) is str for op in op_names)
+                and all(type(dt) is str for dt in width)
+                and set(map(type, flat)) <= {int} and (dims >= 0).all()
+                and finite and np.isfinite(flops).all()
+                and np.isfinite(macs).all()
+                and np.isfinite(param_bytes).all()):
+            return None
+        canon = {op: _canonical_op(op) for op in op_names}
+        op_list = list(map(canon.__getitem__, raw_ops))
+        cols = NodeColumns(
+            op=np.fromiter(map(OP_INDEX.__getitem__, op_list), np.int64, n),
+            attrs=attr_matrix(attrs),
+            dtype_bytes=np.fromiter(map(width.__getitem__, dtypes),
+                                    np.int64, n),
+            rank=rank, dims=dims, flops=flops, macs=macs,
+            param_bytes=param_bytes)
+    except _UNREADABLE:
+        return None
+    return GraphArrays(cols=cols, edges=ends,
+                       content=list(zip(op_list, map(tuple, shapes), dtypes)),
+                       meta=meta)
 
 
 def from_json_file(path: str) -> OpGraph:

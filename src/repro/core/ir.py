@@ -4,6 +4,9 @@ This is the analogue of the paper's Relay IR stage (DIPPM §3.1): every
 frontend (jaxpr tracer, serialized JSON graphs) lowers to :class:`OpGraph`,
 and every downstream component (Node Feature Generator, Static Feature
 Generator, cost model, dataset builder) consumes only :class:`OpGraph`.
+The serving path's JSON parse also yields :class:`GraphArrays`, the same
+graph as arrays, which the fingerprint and the feature generators read
+as they read an :class:`OpGraph`.
 
 Design notes
 ------------
@@ -24,9 +27,13 @@ import hashlib
 import itertools
 import json
 from operator import attrgetter
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (TYPE_CHECKING, Any, Dict, Iterable, List, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
+
+if TYPE_CHECKING:
+    from .node_features import NodeColumns
 
 # ---------------------------------------------------------------------------
 # Operator vocabulary
@@ -140,13 +147,13 @@ def _op_dtype_word(op: str, dtype: str) -> int:
                                           digest_size=8).digest(), "little")
 
 
-def _content_labels(nodes: Sequence["OpNode"]) -> np.ndarray:
-    """Each node's initial WL label, a ``uint64`` hash of its content
+def _content_labels(keys: Sequence[Tuple[str, Sequence[int], str]]
+                    ) -> np.ndarray:
+    """Each node's initial WL label, a ``uint64`` hash of its content key
     ``(op, out_shape, dtype)``: the op/dtype word, the rank, and the sum
     of the dims each mixed with its position. Hashed once per distinct
     content in the graph (a few dozen across hundreds of nodes)."""
-    n = len(nodes)
-    keys = list(map(_CONTENT_KEY, nodes))
+    n = len(keys)
     first: Dict[Tuple[Any, ...], int] = {}   # content → its first node
     try:
         first_of = np.fromiter(map(first.setdefault, keys, range(n)),
@@ -194,6 +201,30 @@ def _edge_positions(nodes: Sequence["OpNode"],
         raise KeyError(int(ends[~known][0]))
     at = order[at]
     return at[:, 0], at[:, 1]
+
+
+def _wl_fingerprint(keys: Sequence[Tuple[str, Sequence[int], str]],
+                    src: np.ndarray, dst: np.ndarray,
+                    meta: Dict[str, Any]) -> str:
+    """:meth:`OpGraph.fingerprint` of a graph given as each node's
+    content key and the list positions of its edges' ends."""
+    n, e = len(keys), len(src)
+    with np.errstate(over="ignore"):
+        labels = _content_labels(keys)
+        for _ in range(_WL_ROUNDS):
+            mixed = _mix64(labels)
+            into = np.zeros(n, np.uint64)
+            np.add.at(into, dst, mixed[src])
+            out = np.zeros(n, np.uint64)
+            np.add.at(out, src, mixed[dst])
+            labels = _mix64(labels + into + out * _SUCC_MUL)
+        pairs = _mix64(_mix64(labels[src] ^ _PAIR_SALT) + labels[dst])
+        sums = np.array([labels.sum(dtype=np.uint64),
+                         pairs.sum(dtype=np.uint64)], "<u8")
+    h = hashlib.sha256(f"{n}|{e}".encode())
+    h.update(sums.tobytes())
+    h.update(json.dumps(meta, sort_keys=True, default=str).encode())
+    return h.hexdigest()
 
 
 # ---------------------------------------------------------------------------
@@ -309,9 +340,6 @@ class OpGraph:
         return order
 
     # -- aggregate statistics (consumed by SFG + cost model) ----------------
-    def total_flops(self) -> float:
-        return float(sum(nd.flops for nd in self.nodes))
-
     def total_macs(self) -> float:
         return float(sum(nd.macs for nd in self.nodes))
 
@@ -374,24 +402,9 @@ class OpGraph:
         memo = self.__dict__.get("_fingerprint")
         if memo is not None:
             return memo
-        n, e = len(self.nodes), len(self.edges)
         src, dst = _edge_positions(self.nodes, self.edges)
-        with np.errstate(over="ignore"):
-            labels = _content_labels(self.nodes)
-            for _ in range(_WL_ROUNDS):
-                mixed = _mix64(labels)
-                into = np.zeros(n, np.uint64)
-                np.add.at(into, dst, mixed[src])
-                out = np.zeros(n, np.uint64)
-                np.add.at(out, src, mixed[dst])
-                labels = _mix64(labels + into + out * _SUCC_MUL)
-            pairs = _mix64(_mix64(labels[src] ^ _PAIR_SALT) + labels[dst])
-            sums = np.array([labels.sum(dtype=np.uint64),
-                             pairs.sum(dtype=np.uint64)], "<u8")
-        h = hashlib.sha256(f"{n}|{e}".encode())
-        h.update(sums.tobytes())
-        h.update(json.dumps(self.meta, sort_keys=True, default=str).encode())
-        fp = h.hexdigest()
+        fp = _wl_fingerprint(list(map(_CONTENT_KEY, self.nodes)), src, dst,
+                             self.meta)
         self.__dict__["_fingerprint"] = fp
         return fp
 
@@ -420,6 +433,35 @@ class OpGraph:
     @staticmethod
     def loads(s: str) -> "OpGraph":
         return OpGraph.from_json(json.loads(s))
+
+
+@dataclasses.dataclass
+class GraphArrays:
+    """An operator graph held as arrays, with no ``OpNode`` per node:
+    what ``frontends.from_json_arrays`` makes of a ``repro.opgraph.v1``
+    document. It answers what the serving path asks of an
+    :class:`OpGraph` (``num_nodes``, ``edges``, ``meta``,
+    :meth:`fingerprint`, and through ``cols`` the node features) with
+    the same values as the ``OpGraph`` that ``frontends.from_json``
+    builds of that document."""
+
+    #: The nodes' ``node_features.NodeColumns``.
+    cols: "NodeColumns"
+    #: [E, 2] int64 (src, dst) node positions, sorted and unique, with
+    #: no self-loop: the edges of ``filter_and_preprocess``'s output.
+    edges: np.ndarray
+    #: Each node's content key ``(op, out_shape, dtype)``, node order.
+    content: List[Tuple[str, Tuple[int, ...], str]]
+    meta: Dict[str, Any]
+
+    @property
+    def num_nodes(self) -> int:
+        return len(self.content)
+
+    def fingerprint(self) -> str:
+        """:meth:`OpGraph.fingerprint` of the same graph."""
+        return _wl_fingerprint(self.content, self.edges[:, 0],
+                               self.edges[:, 1], self.meta)
 
 
 # ---------------------------------------------------------------------------

@@ -19,9 +19,12 @@ __all__ = ["TraceAnnotation", "SUBMIT", "PARSE", "FINGERPRINT",
 
 #: One request in ``submit``/``submit_json``, caller's thread (``req``).
 SUBMIT = "dippm.submit"
-#: ``frontends.from_json`` of the request's document (``nodes``).
+#: The parse of the request's document: ``path`` is ``"arrays"``
+#: (``frontends.from_json_arrays``) or ``"objects"``
+#: (``frontends.from_json``); ``nodes``.
 PARSE = "dippm.parse"
-#: ``OpGraph.fingerprint`` (cache and quarantine key; ``nodes``).
+#: ``fingerprint()`` of the parsed graph (cache and quarantine key;
+#: ``nodes``).
 FINGERPRINT = "dippm.fingerprint"
 #: ``sample_from_graph``: node features, static features, padding
 #: (``nodes``).

@@ -30,8 +30,9 @@ import numpy as np
 
 from ..core.batching import (DEFAULT_BUCKETS, GraphSample, cut_to_bucket,
                              pad_sample)
-from ..core.node_features import NODE_FEATURE_DIM, node_feature_matrix
-from ..core.static_features import static_features
+from ..core.node_features import (NODE_FEATURE_DIM, columns_feature_matrix,
+                                  node_columns)
+from ..core.static_features import columns_static_features
 from ..perfmodel.cost_model import estimate
 from ..perfmodel.devices import DEVICES
 from ..zoo.families import TABLE2_FRACTIONS, family_variants, trace_family
@@ -94,10 +95,11 @@ def _trace_and_label(family: str, cfg: Dict, device_name: str,
                      noise_sigma: float) -> DatasetRecord:
     g = trace_family(family, cfg)
     est = estimate(g, DEVICES[device_name], noise_sigma=noise_sigma)
+    cols = node_columns(g.nodes)
     return DatasetRecord(
-        x=node_feature_matrix(g),
+        x=columns_feature_matrix(cols),
         edges=np.asarray(g.edges, dtype=np.int32).reshape(-1, 2),
-        static=static_features(g),
+        static=columns_static_features(cols, g.meta),
         y=est.as_targets(),
         family=family,
         n_nodes=g.num_nodes,

@@ -219,8 +219,8 @@ def _trace_lm_entry(entry: Dict[str, Any], device_name: str,
     from jax import ShapeDtypeStruct as S
     from ..configs import get_smoke_config
     from ..core.frontends import from_jax
-    from ..core.node_features import node_feature_matrix
-    from ..core.static_features import static_features
+    from ..core.node_features import columns_feature_matrix, node_columns
+    from ..core.static_features import columns_static_features
     from ..models import lm
     from ..perfmodel.cost_model import estimate
     from ..perfmodel.devices import DEVICES
@@ -245,10 +245,11 @@ def _trace_lm_entry(entry: Dict[str, Any], device_name: str,
     g = from_jax(fwd, pspecs, *data_specs,
                  meta={"family": arch, "batch": batch, "seq": seq})
     est = estimate(g, DEVICES[device_name], noise_sigma=noise_sigma)
+    cols = node_columns(g.nodes)
     return DatasetRecord(
-        x=node_feature_matrix(g),
+        x=columns_feature_matrix(cols),
         edges=np.asarray(g.edges, dtype=np.int32).reshape(-1, 2),
-        static=static_features(g),
+        static=columns_static_features(cols, g.meta),
         y=est.as_targets(),
         family=arch,
         n_nodes=g.num_nodes,

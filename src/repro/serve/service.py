@@ -88,7 +88,7 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -96,7 +96,7 @@ from ..core.batching import (packed_rung_ladder, resolve_packed_budgets,
                              sample_from_graph)
 from ..core.engine import EngineConfig, PredictionEngine
 from ..core import spans
-from ..core.ir import GraphValidationError, OpGraph
+from ..core.ir import GraphArrays, GraphValidationError, OpGraph
 from .cache import CacheWaiter, PredictionCache
 from .fleet import NoHealthyReplicaError
 from .lifecycle import (BreakerConfig, DeadlineExceededError,
@@ -196,7 +196,13 @@ class ServeStats:
     executions spent on that isolation; ``quarantine_fastfail``
     resubmits rejected at the door; ``quarantine_entries`` fingerprints
     currently quarantined; ``invalid`` documents rejected by
-    ``submit_json`` validation; ``breaker_states`` / ``revivals``
+    ``submit_json`` validation; of the valid ``repro.opgraph.v1``
+    documents that ``submit_json`` took, ``array_parses`` were parsed
+    straight into arrays and ``array_parse_fallbacks`` were not in the
+    canonical form that ``frontends.from_json_arrays`` takes (or had a
+    field it cannot read) and went through ``from_json``; raw exporter
+    node lists count in neither;
+    ``breaker_states`` / ``revivals``
     mirror the fleet's circuit breakers (closed replicas take traffic;
     a revival is a half-open probe that re-closed one); ``draining`` is
     True once :meth:`PredictionService.drain` / ``close`` stopped
@@ -214,6 +220,8 @@ class ServeStats:
     quarantine_fastfail: int = 0
     quarantine_entries: int = 0
     invalid: int = 0
+    array_parses: int = 0
+    array_parse_fallbacks: int = 0
     draining: bool = False
     breaker_states: Tuple[str, ...] = ()
     revivals: int = 0
@@ -302,6 +310,8 @@ class PredictionService:
         self._poisoned = 0
         self._bisect_runs = 0
         self._invalid = 0
+        self._array_parses = 0
+        self._array_parse_fallbacks = 0
         self._latencies: deque = deque(maxlen=self.serve_cfg.latency_window)
         #: Service-wide request ids: ``req`` on the trace spans.
         self._req_ids = itertools.count()
@@ -356,7 +366,8 @@ class PredictionService:
         with spans.TraceAnnotation(spans.SUBMIT, req=req_id):
             return self._submit(g, deadline_ms, req_id)
 
-    def _submit(self, g: OpGraph, deadline_ms: Optional[float],
+    def _submit(self, g: Union[OpGraph, GraphArrays],
+                deadline_ms: Optional[float],
                 req_id: int) -> PredictionFuture:
         # admission stops at drain for EVERY path — a cache hit or
         # quarantine fast-fail must not slip past a closed queue
@@ -401,6 +412,13 @@ class PredictionService:
         """Enqueue a portable serialized graph (``repro.opgraph.v1`` or
         a raw exporter node list) — the ``from_json`` frontend.
 
+        A canonical ``repro.opgraph.v1`` document (as ``OpGraph.to_json``
+        writes it) is parsed straight into arrays
+        (``frontends.from_json_arrays``): no ``OpNode`` per node, and
+        the same sample and fingerprint as ``from_json``'s ``OpGraph``.
+        Any other document goes through ``from_json``, which
+        canonicalises it or names its fault.
+
         A structurally invalid document returns an already-rejected
         future carrying :class:`~repro.core.ir.GraphValidationError`
         (with node-level context) without touching the queue — callers
@@ -408,13 +426,16 @@ class PredictionService:
         future-based error surface instead of a mix of raises and
         rejections.
         """
-        from ..core.frontends import from_json
+        from ..core.frontends import from_json, from_json_arrays
         req_id = next(self._req_ids)
         with spans.TraceAnnotation(spans.SUBMIT, req=req_id):
             try:
                 with spans.TraceAnnotation(spans.PARSE) as parse:
-                    g = from_json(doc)
-                    parse.set_metadata(nodes=g.num_nodes)
+                    g = from_json_arrays(doc)
+                    path = "objects" if g is None else "arrays"
+                    if g is None:
+                        g = from_json(doc)
+                    parse.set_metadata(path=path, nodes=g.num_nodes)
             except GraphValidationError as e:
                 fut = PredictionFuture()
                 fut._reject(e)
@@ -423,6 +444,13 @@ class PredictionService:
                     self._failed += 1
                     self._invalid += 1
                 return fut
+            if path == "arrays":
+                with self._state:
+                    self._array_parses += 1
+            elif (isinstance(doc, dict)
+                  and doc.get("schema") == "repro.opgraph.v1"):
+                with self._state:
+                    self._array_parse_fallbacks += 1
             return self._submit(g, deadline_ms, req_id)
 
     def submit_jax(self, forward, param_specs, *input_specs,
@@ -747,6 +775,8 @@ class PredictionService:
                 quarantine_fastfail=q.fastfails if q is not None else 0,
                 quarantine_entries=len(q) if q is not None else 0,
                 invalid=self._invalid,
+                array_parses=self._array_parses,
+                array_parse_fallbacks=self._array_parse_fallbacks,
                 draining=self._queue.closed,
                 breaker_states=tuple(
                     getattr(self.engine, "breaker_states", ())),

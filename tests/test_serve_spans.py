@@ -157,3 +157,34 @@ def test_predictions_carry_their_serving_record(packed_dippm):
         assert served["queue_wait_ms"] >= 0
         assert "serving" not in pred.meta
     assert hit.serving is None and hit == preds[0]
+
+
+def test_parse_span_names_its_path_and_the_counters_agree(packed_dippm,
+                                                          tmp_path):
+    """A canonical v1 document takes the array path; a v1 document with
+    its nodes out of id order and one with a dtype that is not a string
+    take the object path and count as fallbacks; a raw exporter list
+    takes the object path and counts in neither counter."""
+    shuffled = GRAPHS[1].to_json()
+    shuffled["nodes"].reverse()
+    raw = GRAPHS[2].to_json()
+    del raw["schema"]
+    odd_dtype = GRAPHS[3].to_json()
+    odd_dtype["nodes"][0]["dtype"] = 4
+    docs = [GRAPHS[0].to_json(), shuffled, raw, odd_dtype]
+
+    def serve():
+        with packed_dippm.serve(max_wait_ms=60_000.0, cache_size=None) as svc:
+            futs = [svc.submit_json(d) for d in docs]
+            svc.flush()
+            for f in futs:
+                f.result(60)
+            return svc.stats
+
+    st, events = traced(serve, tmp_path)
+    parses = [e[4] for e in events if e[0] == span_names.PARSE]
+    assert [p["path"] for p in parses] == ["arrays", "objects", "objects",
+                                           "objects"]
+    assert [p["nodes"] for p in parses] == [g.num_nodes for g in GRAPHS[:4]]
+    assert (st.submitted, st.completed) == (4, 4)
+    assert (st.array_parses, st.array_parse_fallbacks) == (1, 2)
