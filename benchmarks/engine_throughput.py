@@ -13,11 +13,13 @@ Sweeps a 64-model zoo grid (4 families × 16 variants), times
 * **loop**   — ``[dippm.predict_graph(g) for g in graphs]`` (today's
   facade: each call a submit/flush round trip through the shared
   serving path onto compiled engine bins), and
-* **engine** — ``dippm.predict_many(graphs)`` (bucketed, batched, one
-  compiled apply per padded shape),
+* **engine** — ``dippm.predict_many(graphs)`` (packed bins, one
+  compiled apply per ``(P, Q, G)`` rung),
 
-and checks all paths produce identical predictions (max |Δ| ≤ 1e-5 on
-latency/energy/memory). Tracing the 64 graphs is *not* timed — all
+and checks all paths agree on latency/energy/memory within the
+tolerance the dense, sparse and packed layouts are held to
+(``|Δ| ≤ 1e-5 + 1e-5·|x|``: the eager row is the dense layout, the
+engine serves packed bins). Tracing the 64 graphs is *not* timed — all
 paths consume the same pre-built ``OpGraph`` list.
 
     PYTHONPATH=src python -m benchmarks.engine_throughput
@@ -88,12 +90,11 @@ def run(n_graphs: int = 64, hidden: int = 128, repeats: int = 3):
     batches_per_sweep = (st.batches_run - batches0) // repeats
     stats = dippm.engine().stats.snapshot()    # counters of the timed runs
 
-    diffs = [
-        max(abs(a.latency_ms - b.latency_ms), abs(a.energy_j - b.energy_j),
-            abs(a.memory_mb - b.memory_mb))
-        for ref, out in ((eager_out, many_out), (loop_out, many_out))
-        for a, b in zip(ref, out)
-    ]
+    pairs = [(np.array([a.latency_ms, a.energy_j, a.memory_mb]),
+              np.array([b.latency_ms, b.energy_j, b.memory_mb]))
+             for ref, out in ((eager_out, many_out), (loop_out, many_out))
+             for a, b in zip(ref, out)]
+    diffs = [np.abs(a - b).max() for a, b in pairs]
     res = {
         "n_graphs": len(graphs),
         "eager_pred_per_s": round(len(graphs) / eager_s, 2),
@@ -102,6 +103,8 @@ def run(n_graphs: int = 64, hidden: int = 128, repeats: int = 3):
         "speedup": round(eager_s / many_s, 2),
         "loop_speedup": round(eager_s / loop_s, 2),
         "max_abs_diff": float(np.max(diffs)),
+        "within_tol": all(np.allclose(b, a, atol=1e-5, rtol=1e-5)
+                          for a, b in pairs),
         "batches_per_sweep": batches_per_sweep,
         "compiles": compiles,
         "cache_entries": stats.cache_entries,
@@ -124,7 +127,7 @@ def main():
           f"{res['loop_speedup']:.2f}x eager)")
     print(f"engine : {res['engine_pred_per_s']:9.2f} predictions/s "
           f"({res['compiles']} compiles, {res['batches_per_sweep']} "
-          f"batched calls/sweep)")
+          f"packed bins/sweep)")
     print(f"stats  : {res['cache_entries']} cache entries, "
           f"{res['recompiles']} recompiles, "
           f"{res['padding_waste_frac']:.1%} of node rows padding")
@@ -135,8 +138,9 @@ def main():
              " (bf16 drift probe runs only under precision='bf16')"))
     print(f"speedup: {res['speedup']:.2f}x   "
           f"max |diff| = {res['max_abs_diff']:.2e}")
-    ok = res["speedup"] >= 3.0 and res["max_abs_diff"] <= 1e-5
-    print("PASS" if ok else "FAIL", "(target: ≥3x, |diff| ≤ 1e-5)")
+    ok = res["speedup"] >= 3.0 and res["within_tol"]
+    print("PASS" if ok else "FAIL",
+          "(target: ≥3x, |diff| ≤ 1e-5 + 1e-5·|x|)")
     return 0 if ok else 1
 
 

@@ -42,12 +42,24 @@ import sys
 from repro.compile_cache import enable_compile_cache
 
 from .common import bench_hardware, timed, write_json
-from .packed_batching import _mixed_zoo
 
 VARIANTS = ("graphsage", "gcn", "gat", "gin", "mlp")
 #: Full-bin packed shape under the default budgets (4096-node ladder
 #: top: Q = 1.625·P edges, G = P/16 graphs).
 FULL_BIN = {"p": 4096, "q": 6656, "g": 256}
+
+
+def _mixed_zoo(n_graphs: int, seed: int = 0):
+    """DIPPM-like mixed-size sample zoo: 60 % small (8–40 nodes), 30 %
+    medium (50–200), 10 % large (300–700)."""
+    from repro.dataset.builder import synthetic_samples
+    n_small = int(0.6 * n_graphs)
+    n_med = int(0.3 * n_graphs)
+    n_large = n_graphs - n_small - n_med
+    return (synthetic_samples(n_small, seed=seed, n_min=8, n_max=40)
+            + synthetic_samples(n_med, seed=seed + 1, n_min=50, n_max=200)
+            + synthetic_samples(n_large, seed=seed + 2, n_min=300,
+                                n_max=700))
 BASELINE_PATH = os.path.join(os.path.dirname(__file__), "baselines",
                              "fused_mp_roofline.json")
 #: Variants with a true fused MP layer (gat fuses its aggregate only,

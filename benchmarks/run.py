@@ -30,7 +30,7 @@ def main() -> None:
 
     from . import (accuracy_mape, chaos_resilience, engine_throughput,
                    fig3_mig_memory, fig4_scatter, fused_mp, microbench,
-                   packed_batching, roofline_report, serving_fleet,
+                   roofline_report, serving_fleet,
                    serving_latency, sparse_mp, table2_dataset, table4_gnn,
                    table5_mig, train_throughput)
 
@@ -39,7 +39,6 @@ def main() -> None:
         "engine": lambda: engine_throughput.run(),
         "train": lambda: train_throughput.run(),
         "sparse_mp": lambda: sparse_mp.run(),
-        "packed_batching": lambda: packed_batching.run(),
         "fused_mp": lambda: fused_mp.run(),
         "serving_latency": lambda: serving_latency.run(),
         "serving_fleet": lambda: serving_fleet.run(),

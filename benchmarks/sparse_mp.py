@@ -3,16 +3,16 @@
 DIPPM graphs are computation DAGs with ~1–3 edges per node, yet the
 original layers compute over padded dense ``[B, N, N]`` adjacency —
 O(B·N²·F) compute and O(B·N²) batch memory. The sparse path
-(``PMGNSConfig(sparse_mp=True)``) aggregates over a padded edge list
+(``PMGNSConfig(layout="sparse")``) aggregates over a padded edge list
 (``repro.kernels.segment_spmm`` / the lax fallbacks) instead. This gate
 pins three claims at the N=512 bucket with realistic DAG density
 (E ≈ 1.5 N):
 
 * **Equivalence** — sparse and dense predictions agree to ≤ 1e-5 for all
   five layer variants (graphsage/gcn/gat/gin/mlp), and a full scan
-  trainer epoch with ``sparse_mp=True`` reproduces the dense epoch loss
+  trainer epoch with ``layout="sparse"`` reproduces the dense epoch loss
   to float tolerance.
-* **Throughput** — engine predictions/sec ≥ 3× dense for the GAT
+* **Throughput** — jitted-apply predictions/sec ≥ 3× dense for the GAT
   variant, whose dense form materializes the ``[B, N, N, heads]``
   attention tensor (the worst O(N²) hot path this PR kills). GraphSAGE
   mean aggregation is a single MXU-friendly matmul, so its CPU-runner
@@ -55,7 +55,7 @@ def _equivalence_deltas(samples, hidden: int):
     deltas = {}
     for variant in VARIANTS:
         cfg_d = PMGNSConfig(variant=variant, hidden=hidden)
-        cfg_s = PMGNSConfig(variant=variant, hidden=hidden, sparse_mp=True)
+        cfg_s = PMGNSConfig(variant=variant, hidden=hidden, layout="sparse")
         params = pmgns_init(jax.random.PRNGKey(0), cfg_d)
         yd = np.asarray(pmgns_infer(params, cfg_d, batch_d))
         ys = np.asarray(pmgns_infer(params, cfg_s, batch_s))
@@ -63,21 +63,38 @@ def _equivalence_deltas(samples, hidden: int):
     return deltas
 
 
-def _throughput(samples, variant: str, hidden: int, repeats: int):
+def _throughput(samples, variant: str, hidden: int, repeats: int,
+                chunk: int = 16):
+    """Each training layout's jitted apply over the same chunks, batch
+    assembly included. (The prediction engine serves packed bins
+    whatever the layout, so the layouts are compared below it.)"""
     import jax
+    import jax.numpy as jnp
     import numpy as np
-    from repro.core.engine import PredictionEngine
-    from repro.core.gnn import PMGNSConfig, pmgns_init
+    from repro.core.batching import collate, edge_bucket_for
+    from repro.core.gnn import PMGNSConfig, pmgns_infer, pmgns_init
 
     cfg_d = PMGNSConfig(variant=variant, hidden=hidden)
-    cfg_s = PMGNSConfig(variant=variant, hidden=hidden, sparse_mp=True)
+    cfg_s = PMGNSConfig(variant=variant, hidden=hidden, layout="sparse")
     params = pmgns_init(jax.random.PRNGKey(0), cfg_d)
-    eng_d = PredictionEngine(params, cfg_d)
-    eng_s = PredictionEngine(params, cfg_s)
-    yd = eng_d.predict_samples(samples)          # warm compiled fns
-    ys = eng_s.predict_samples(samples)
-    _, t_d = timed(lambda: eng_d.predict_samples(samples), repeats=repeats)
-    _, t_s = timed(lambda: eng_s.predict_samples(samples), repeats=repeats)
+    chunks = [samples[i:i + chunk] for i in range(0, len(samples), chunk)]
+    e_bucket = edge_bucket_for(max(s.n_edges for s in samples))
+
+    def predict(cfg):
+        sparse = cfg.resolved_layout == "sparse"
+        fn = jax.jit(lambda p, b: pmgns_infer(p, cfg, b))
+
+        def run():
+            return np.concatenate([np.asarray(fn(params, {
+                k: jnp.asarray(v) for k, v in collate(
+                    c, sparse=sparse, edge_bucket=e_bucket).items()
+                if k != "y"})) for c in chunks])
+        return run
+
+    run_d, run_s = predict(cfg_d), predict(cfg_s)
+    yd, ys = run_d(), run_s()                    # warm compiled fns
+    _, t_d = timed(run_d, repeats=repeats)
+    _, t_s = timed(run_s, repeats=repeats)
     return {
         "dense_pred_per_s": round(len(samples) / t_d, 2),
         "sparse_pred_per_s": round(len(samples) / t_s, 2),
@@ -97,7 +114,7 @@ def _trainer_epoch_match(n_samples: int, hidden: int):
     common = dict(epochs=2, batch_size=8, lr=1e-3, seed=0, scan_steps=16)
     _, h_d = train_pmgns(PMGNSConfig(hidden=hidden), samples, (),
                          TrainConfig(mode="scan", **common))
-    _, h_s = train_pmgns(PMGNSConfig(hidden=hidden, sparse_mp=True),
+    _, h_s = train_pmgns(PMGNSConfig(hidden=hidden, layout="sparse"),
                          samples, (), TrainConfig(mode="scan", **common))
     rel = max(
         abs(a["train_loss"] - b["train_loss"])

@@ -3,8 +3,8 @@ rapid design-space exploration for the inference performance of a model").
 
 Sweeps a ViT family over (depth × width × batch) with the **batched
 prediction engine** (``DIPPM.predict_zoo``): all 27 candidates are traced,
-bucketed by padded size, and scored in a handful of jit-compiled batched
-apply calls — no candidate is ever executed. Prints the Pareto-optimal
+bin-packed onto packed bins, and scored in a handful of jit-compiled
+batched apply calls — no candidate is ever executed. Prints the Pareto-optimal
 configurations under a memory budget plus engine throughput stats.
 
     PYTHONPATH=src python examples/design_space_exploration.py

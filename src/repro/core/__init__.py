@@ -14,11 +14,10 @@ from .batching import (GraphSample, collate, collate_packed,
                        edge_bucket_for, edge_floor,
                        DEFAULT_BUCKETS, DEFAULT_NODE_BUDGET)
 from .gnn import (PMGNSConfig, pmgns_init, pmgns_apply, pmgns_infer,
-                  make_infer_fn, make_staged_packed_infer_fn,
+                  make_staged_packed_infer_fn,
                   packed_staging_layout, encode_targets, decode_targets,
                   huber, mape, TARGET_NAMES)
 from .mig import (predict_mig, predict_tpu_slice, predict_pods,
                   MIG_PROFILES, TPU_V5E_SLICES, mig_utilization)
 from .predictor import DIPPM, Prediction, make_prediction
-from .engine import (EngineConfig, EngineStats, PredictionEngine,
-                     INFERENCE_BUCKETS)
+from .engine import EngineConfig, EngineStats, PredictionEngine

@@ -31,7 +31,7 @@ Storage is **sparse until collate** in every layout: a
 :class:`GraphSample` carries an ``[E, 2]`` edge list, and per-batch
 arrays are materialized only when a batch is assembled (:func:`collate`,
 :func:`collate_packed`, :func:`stack_epoch_segments`, the prediction
-engine's chunk builder). Host memory for a dataset is therefore
+engine's staging). Host memory for a dataset is therefore
 O(nodes + edges) per sample instead of O(N²) — at the paper's
 10,508-graph scale the dense layout is tens of GB before training
 starts; the sparse layout is tens of MB.
@@ -172,11 +172,11 @@ def edge_bucket_for(n_edges: int) -> int:
 def edge_floor(node_bucket: int) -> int:
     """Per-node-bucket edge-bucket floor at typical DAG density (~2/node).
 
-    The single source of truth shared by the prediction engine's chunk
-    builder and the trainer's segment builder (both previously re-derived
-    it): chunks/segments at or below this density all land on one
-    compiled shape per node bucket, and only rare denser batches escape
-    to a larger edge bucket.
+    The single source of truth for the trainer's bucketed batches
+    (:func:`batches_by_bucket`, :func:`stack_epoch_segments`): batches
+    at or below this density all land on one compiled shape per node
+    bucket, and only rare denser batches escape to a larger edge
+    bucket.
     """
     return edge_bucket_for(2 * node_bucket)
 
@@ -213,10 +213,8 @@ def group_by_bucket(
 ) -> Dict[int, List[int]]:
     """Group sample *indices* by padded bucket size, preserving input order.
 
-    Shared by training batching (:func:`batches_by_bucket`), the stacked
-    scan schedule (:func:`stack_epoch_segments`), and the inference engine
-    (``repro.core.engine``), which needs the indices to restore input
-    order after per-bucket batched execution.
+    Shared by training batching (:func:`batches_by_bucket`) and the
+    stacked scan schedule (:func:`stack_epoch_segments`).
     """
     by_bucket: Dict[int, List[int]] = {}
     for i, s in enumerate(samples):
@@ -274,15 +272,16 @@ def pad_sample(
 
 def cut_to_bucket(sample: GraphSample,
                   buckets: Sequence[int] = DEFAULT_BUCKETS) -> GraphSample:
-    """``sample`` cut to the top bucket, for the layouts that need it.
+    """``sample`` cut to the top bucket, for the training layouts.
 
-    The dense and sparse bucketed layouts pad every graph to a node
-    bucket (dense: ``[B, N, N]`` adjacency), so a graph over the top
-    bucket keeps its ``buckets[-1]`` heaviest nodes by the last node
-    feature (``log1p(flops)``), in their order, with the edges among
-    them remapped; its static features still describe the whole graph.
-    A sample that fits is returned as it is. The packed layout never
-    calls this: it serves every node.
+    The dense and sparse training layouts pad every graph to a node
+    bucket (dense: ``[B, N, N]`` adjacency), so the dataset builder and
+    the trainer keep a graph over the top bucket to its ``buckets[-1]``
+    heaviest nodes by the last node feature (``log1p(flops)``), in their
+    order, with the edges among them remapped; its static features still
+    describe the whole graph. A sample that fits is returned as it is.
+    Prediction never calls this: the engine's packed bins serve every
+    node.
     """
     cap = buckets[-1]
     n = sample.n_nodes

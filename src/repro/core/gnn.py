@@ -7,8 +7,8 @@ same skeleton with the message-passing layer swapped, exactly the paper's
 ablation design.
 
 All layers operate on batches (``repro.core.batching``) in one of three
-layouts, selected by ``PMGNSConfig.layout`` (``sparse_mp`` is the legacy
-alias for ``layout="sparse"``):
+layouts. ``PMGNSConfig.layout`` selects the trainer's; inference always
+runs packed (``repro.core.engine``), whatever layout trained the model:
 
     x     [B, N, F]     node features
     mask  [B, N]        node validity
@@ -41,7 +41,8 @@ dispatchers (``repro.kernels.ops``): dense SAGE/GCN/GIN hit the blocked
 MXU SpMM (``repro.kernels.sage_spmm``), sparse layers hit the segment
 kernels (``repro.kernels.segment_spmm``), and sparse GAT additionally
 uses the edge-softmax kernel. Dense GAT has no Pallas attention path
-(the ``[B, N, N, heads]`` tensor is exactly what ``sparse_mp`` removes)
+(the ``[B, N, N, heads]`` tensor is exactly what the sparse layout
+removes)
 and warns once before falling back to jnp; the no-message-passing MLP
 baseline has nothing to accelerate and ignores the flag by design.
 
@@ -215,7 +216,7 @@ def gat_layer(p: Params, x, adj, mask, *, edges=None, edge_mask=None,
     if use_pallas:
         _warn_no_pallas_path(
             "gat_layer (dense)", "The Pallas GAT path is the sparse "
-            "edge-softmax kernel — enable PMGNSConfig(sparse_mp=True).")
+            "edge-softmax kernel — use PMGNSConfig(layout='sparse').")
     # e[b, i, j, h] — attention of dst i over j; explicit masked softmax
     # with a guarded denominator so an all-padding (empty-neighborhood)
     # destination row yields exact zeros instead of relying on post-hoc
@@ -287,22 +288,18 @@ class PMGNSConfig:
     n_targets: int = N_TARGETS
     readout: str = "mean_max"        # graph-level pooling
     use_pallas: bool = False
-    #: Sparse edge-list message passing: batches carry ``edges``/
-    #: ``edge_mask`` instead of the dense ``[B, N, N]`` adjacency, and
-    #: every layer aggregates via segment gather/scatter — O(E·F) and
-    #: O(N·F + E) memory instead of O(N²·F) / O(N²). The dense path
-    #: stays the numerical reference; both agree to ≤1e-5
-    #: (``benchmarks/sparse_mp.py`` gates this). Legacy alias for
-    #: ``layout="sparse"``.
-    sparse_mp: bool = False
-    #: Batch layout: ``"auto"`` (dense, or sparse when ``sparse_mp``),
-    #: ``"dense"``, ``"sparse"``, or ``"packed"`` — the block-diagonal
+    #: The trainer's batch layout: ``"auto"`` (dense), ``"dense"`` (the
+    #: ``[B, N, N]`` adjacency, the numerical reference), ``"sparse"``
+    #: (padded edge lists, segment gather/scatter — O(N·F + E) memory
+    #: instead of O(N²)), or ``"packed"`` — the block-diagonal
     #: flat-node-axis layout (``repro.core.batching.collate_packed``):
     #: one ``x [P, F]`` axis for the whole batch, segment message
     #: passing over globally-offset edges, and a segment-mean/max graph
     #: readout over ``graph_ids`` instead of per-graph masked pooling.
-    #: All three layouts agree to ≤1e-5
-    #: (``benchmarks/packed_batching.py`` gates this).
+    #: Inference is always packed: the parameters do not depend on the
+    #: layout, and ``repro.core.engine.PredictionEngine`` serves any
+    #: trained model on packed bins. All three layouts agree to ≤1e-5
+    #: (``tests/test_engine.py``).
     layout: str = "auto"
     #: Inference precision policy. ``"f32"`` is the reference.
     #: ``"bf16"`` stages request buffers (features/masks/statics) in
@@ -328,10 +325,9 @@ class PMGNSConfig:
 
     @property
     def resolved_layout(self) -> str:
-        """The effective batch layout: explicit ``layout`` wins; ``auto``
-        follows the legacy ``sparse_mp`` flag."""
+        """The effective batch layout: ``auto`` is dense."""
         if self.layout == "auto":
-            return "sparse" if self.sparse_mp else "dense"
+            return "dense"
         if self.layout not in ("dense", "sparse", "packed"):
             raise ValueError(
                 f"layout must be auto|dense|sparse|packed, "
@@ -567,7 +563,7 @@ def pmgns_apply(p: Params, cfg: PMGNSConfig, batch: Dict[str, jnp.ndarray],
     elif layout == "sparse":
         if "edges" not in batch or "edge_mask" not in batch:
             raise ValueError(
-                "PMGNSConfig(sparse_mp=True) needs a sparse batch with "
+                "PMGNSConfig(layout='sparse') needs a sparse batch with "
                 "'edges' and 'edge_mask' — build it via "
                 "collate(samples, sparse=True)")
         mask_mp = mask
@@ -575,9 +571,9 @@ def pmgns_apply(p: Params, cfg: PMGNSConfig, batch: Dict[str, jnp.ndarray],
     else:
         if "adj" not in batch:
             raise ValueError(
-                "PMGNSConfig(sparse_mp=False) needs a dense batch with "
+                "PMGNSConfig(layout='dense') needs a dense batch with "
                 "'adj' — build it via collate(samples) or set "
-                "sparse_mp=True for edge-list batches")
+                "layout='sparse' for edge-list batches")
         mask_mp = mask
         adj, edges, edge_mask = batch["adj"], None, None
     if packed and cfg.resolved_fused and not train:
@@ -618,24 +614,11 @@ def pmgns_infer(p: Params, cfg: PMGNSConfig,
     """Batched inference: padded batch → ``[B, n_targets]`` physical units.
 
     Fuses the forward pass with the ``log1p``-space decode so the whole
-    prediction (apply + decode) is one jittable function — this is the
-    unit the prediction engine (``repro.core.engine``) compiles per
-    ``(node_bucket, batch_bucket)`` shape.
+    prediction (apply + decode) is one jittable function — the unit
+    :func:`make_staged_packed_infer_fn` compiles per ``(P, Q, G)``
+    shape for the prediction engine (``repro.core.engine``).
     """
     return decode_targets(pmgns_apply(p, cfg, batch, train=False))
-
-
-def make_infer_fn(cfg: PMGNSConfig):
-    """Jitted ``(params, batch) → [B, n_targets]`` closure over ``cfg``.
-
-    Each distinct padded batch shape triggers exactly one compilation;
-    callers that bucket shapes (the engine) therefore pay a bounded
-    number of compiles for an unbounded stream of graphs.
-    """
-    @jax.jit
-    def infer(p: Params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        return pmgns_infer(p, cfg, batch)
-    return infer
 
 
 def packed_staging_layout(cfg: PMGNSConfig, p: int, q: int,

@@ -200,13 +200,13 @@ class DIPPM:
         """The batched prediction engine for this predictor.
 
         With no arguments, returns the cached default-config engine that
-        ``predict_many`` / ``predict_zoo`` use. Keyword overrides are
-        :class:`repro.core.engine.EngineConfig` fields (``buckets``,
-        ``max_batch``, ``extended_static``) and return a **fresh**,
-        un-cached engine — the default engine (and its compiled-function
-        cache and stats) is left untouched, so sweeps through
-        ``predict_many`` keep their bit-for-bit equivalence with
-        ``predict_graph`` regardless of custom engines in flight.
+        ``predict_graph`` / ``predict_many`` / ``predict_zoo`` use.
+        Keyword overrides are :class:`repro.core.engine.EngineConfig`
+        fields (``node_budget``, ``edge_budget``, ``graph_budget``,
+        ``extended_static``, ...) and return a **fresh**, un-cached
+        engine — the default engine (and its compiled-function cache and
+        stats) is left untouched. Every engine serves packed bins,
+        whatever ``cfg.layout`` the model was trained in.
         """
         from .engine import EngineConfig, PredictionEngine
         if overrides:
@@ -223,10 +223,9 @@ class DIPPM:
         """Predict many graphs at once, preserving input order.
 
         Equivalent to ``[self.predict_graph(g) for g in graphs]`` but
-        bucketed + batched (or bin-packed, with a
-        ``PMGNSConfig(layout="packed")`` model): one compiled apply per
-        padded shape instead of one eager apply per graph. This is the
-        entry point for zoo sweeps.
+        bin-packed: mixed-size graphs share packed bins, one compiled
+        apply per ``(P, Q, G)`` rung, whatever layout the model was
+        trained in. This is the entry point for zoo sweeps.
 
         With ``return_stats=True`` returns ``(predictions, stats)``
         where ``stats`` is a detached

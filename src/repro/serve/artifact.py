@@ -170,6 +170,18 @@ def save_artifact(path: str, params, cfg: PMGNSConfig,
     return path
 
 
+def _config_from_dict(doc: Dict[str, Any]) -> PMGNSConfig:
+    """A saved config back as a :class:`PMGNSConfig`. Keys this library
+    does not know are dropped, except the retired ``sparse_mp`` alias:
+    set, with ``layout`` left ``"auto"``, it meant the sparse training
+    layout, and the config keeps it."""
+    doc = dict(doc)
+    if doc.pop("sparse_mp", False) and doc.get("layout", "auto") == "auto":
+        doc["layout"] = "sparse"
+    known = {f.name for f in dataclasses.fields(PMGNSConfig)}
+    return PMGNSConfig(**{k: v for k, v in doc.items() if k in known})
+
+
 def _load_pickle_fallback(path: str) -> Tuple[Dict, PMGNSConfig, Dict]:
     """Deprecated v1 loader: the legacy ``DIPPM.save`` pickle blob."""
     import pickle
@@ -180,8 +192,8 @@ def _load_pickle_fallback(path: str) -> Tuple[Dict, PMGNSConfig, Dict]:
         DeprecationWarning, stacklevel=3)
     with open(path, "rb") as f:
         blob = pickle.load(f)
-    return blob["params"], blob["cfg"], {"schema_version": 1,
-                                         "format": "pickle"}
+    return blob["params"], _config_from_dict(vars(blob["cfg"])), {
+        "schema_version": 1, "format": "pickle"}
 
 
 def load_artifact(path: str) -> Tuple[Dict, PMGNSConfig, Dict[str, Any]]:
@@ -216,7 +228,5 @@ def load_artifact(path: str) -> Tuple[Dict, PMGNSConfig, Dict[str, Any]]:
         manifest = doc.get("params", {})
         flat = {key: _decode_leaf(key, spec, z)
                 for key, spec in manifest.items()}
-    known = {f.name for f in dataclasses.fields(PMGNSConfig)}
-    cfg_doc = {k: v for k, v in doc.get("cfg", {}).items() if k in known}
-    return _unflatten(flat), PMGNSConfig(**cfg_doc), dict(
+    return _unflatten(flat), _config_from_dict(doc.get("cfg", {})), dict(
         doc.get("metadata", {}))

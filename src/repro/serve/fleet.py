@@ -40,7 +40,7 @@ sustains. :class:`ReplicaPool` scales the backend horizontally:
   like a training job.
 
 The pool duck-types the engine surface the service consumes
-(``engine_cfg`` / ``cfg`` / ``packed`` / ``plan_bins`` / ``run_bin`` /
+(``engine_cfg`` / ``cfg`` / ``plan_bins`` / ``run_bin`` /
 ``warmup`` / ``stats``), so ``PredictionService(engine=pool)`` — or
 ``ServeConfig(replicas=N)`` — is the only wiring needed.
 """
@@ -130,10 +130,6 @@ class ReplicaPool:
     @property
     def cfg(self) -> PMGNSConfig:
         return self.replicas[0].cfg
-
-    @property
-    def packed(self) -> bool:
-        return self.replicas[0].packed
 
     def plan_bins(self, samples: Sequence[GraphSample]) -> List[List[int]]:
         """Same plan as a single engine (pure — no replica state), which

@@ -699,26 +699,18 @@ class PredictionService:
     def warmup(self, rungs=None) -> int:
         """Precompile before traffic; returns functions compiled.
 
-        Packed engines compile the whole ``(P, Q, G)`` budget-rung
-        ladder by default (``rungs=None`` →
+        Compiles the whole ``(P, Q, G)`` budget-rung ladder by default
+        (``rungs=None`` →
         :func:`~repro.core.batching.packed_rung_ladder`; pass a
-        sequence of ``P`` values to select rungs). Bucketed engines
-        treat ``rungs`` as node buckets (default: all of them).
+        sequence of ``P`` values to select rungs).
         """
-        if self.engine.packed:
-            return self.engine.warmup(rungs="all" if rungs is None
-                                      else rungs)
-        return self.engine.warmup(node_buckets=rungs)
+        return self.engine.warmup(rungs="all" if rungs is None else rungs)
 
     def expected_rungs(self) -> int:
         """How many shapes :meth:`warmup` precompiles by default."""
-        if self.engine.packed:
-            nb, eb, gb = resolve_packed_budgets(
-                self.engine.engine_cfg.node_budget,
-                self.engine.engine_cfg.edge_budget,
-                self.engine.engine_cfg.graph_budget)
-            return len(packed_rung_ladder(nb, eb, gb))
-        return len(self.engine.engine_cfg.buckets)
+        ec = self.engine.engine_cfg
+        return len(packed_rung_ladder(*resolve_packed_budgets(
+            ec.node_budget, ec.edge_budget, ec.graph_budget)))
 
     @property
     def draining(self) -> bool:

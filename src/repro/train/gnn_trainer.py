@@ -10,7 +10,7 @@ The trainer is built in four layers:
 1. **Storage** — samples hold sparse edge lists
    (``repro.core.batching.GraphSample``); the dense ``[B, N, N]``
    adjacency exists only inside batch assembly, so host memory is
-   O(nodes + edges) per sample. With ``PMGNSConfig(sparse_mp=True)``
+   O(nodes + edges) per sample. With ``PMGNSConfig(layout="sparse")``
    the adjacency never exists at all: segments carry padded edge lists
    (``edges [S, B, E, 2]`` + ``edge_mask``) and the model aggregates by
    segment gather/scatter — same schedule, same numerics (within float
@@ -209,8 +209,9 @@ def predict_batch(params, cfg: PMGNSConfig,
     """Physical-unit predictions [n, 3] for a list of samples.
 
     Routed through the batched prediction engine (``repro.core.engine``)
-    — bucketed, batched, one compiled apply per padded shape — so eval
-    and serving share a single inference implementation. A one-slot
+    — packed bins, one compiled apply per ``(P, Q, G)`` rung, whatever
+    layout ``cfg`` trains in — so eval and serving share a single
+    inference implementation. A one-slot
     module cache reuses the engine (and its compiled functions) across
     calls with the *same params object*; callers holding several models,
     or params trees rebuilt per call, should pass their own ``engine``

@@ -101,12 +101,10 @@ def test_pack_edges_rejects_overflow():
 # ---- shared edge-density floor ---------------------------------------------
 
 def test_edge_floor_is_shared_single_source():
-    """Engine and trainer derive per-node-bucket edge floors from ONE
-    helper; the engine's method is a pure delegate."""
-    from repro.core.engine import PredictionEngine
+    """The trainer derives per-node-bucket edge floors from ONE
+    helper."""
     for n in (32, 64, 256, 1024):
         assert edge_floor(n) == edge_bucket_for(2 * n)
-        assert PredictionEngine._edge_floor(n) == edge_floor(n)
     # trainer segments apply the floor: sparse E never below it
     samples = synthetic_samples(9, n_min=4, n_max=20, seed=4)   # bucket 32
     seg = stack_epoch_segments(samples, batch_size=4, sparse=True)[0]

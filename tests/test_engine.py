@@ -4,10 +4,10 @@ import numpy as np
 import pytest
 
 from repro.core import (DIPPM, EngineConfig, PMGNSConfig, PredictionEngine,
-                        pmgns_init)
-from repro.core.batching import (bucket_for, group_by_bucket,
+                        pmgns_infer, pmgns_init)
+from repro.core.batching import (bucket_for, collate, group_by_bucket,
                                  max_batch_for_bucket, next_pow2,
-                                 sample_from_graph)
+                                 packed_rung_ladder, sample_from_graph)
 from repro.core.ir import OpGraph, OpNode
 
 
@@ -22,6 +22,19 @@ def _graph(n_nodes, seed=0):
              for i in range(n_nodes)]
     edges = [(i, i + 1) for i in range(n_nodes - 1)]
     return OpGraph(nodes=nodes, edges=edges, meta={"seed": seed, "n": n_nodes})
+
+
+def _dense_reference(params, samples):
+    """Dense-layout ``pmgns_apply`` (masked per-graph readout), one graph
+    at a time: the reference the engine's packed bins are held to."""
+    dense = PMGNSConfig(hidden=32, layout="dense")
+    return np.concatenate([np.asarray(pmgns_infer(params, dense,
+                                                  collate([s])))
+                           for s in samples])
+
+
+def _phys(pred):
+    return [pred.latency_ms, pred.energy_j, pred.memory_mb]
 
 
 @pytest.fixture(scope="module")
@@ -59,18 +72,20 @@ def test_group_by_bucket_preserves_order():
 # ---- engine behavior -------------------------------------------------------
 
 def test_predict_many_matches_looped_predict_graph(dippm):
-    """Core acceptance: batched vs one-at-a-time, same numbers, same order."""
+    """Core acceptance: batched vs one-at-a-time, same numbers, same
+    order, and both equal dense ``pmgns_apply`` on the same params."""
     sizes = [3, 40, 100, 7, 60, 90, 12, 31, 33]   # spans 3 buckets, shuffled
     graphs = [_graph(n, seed=i) for i, n in enumerate(sizes)]
     loop = [dippm.predict_graph(g) for g in graphs]
     many = dippm.predict_many(graphs)
+    ref = _dense_reference(dippm.params,
+                           [sample_from_graph(g) for g in graphs])
     assert len(many) == len(graphs)
-    for a, b in zip(loop, many):
-        np.testing.assert_allclose(
-            [b.latency_ms, b.energy_j, b.memory_mb],
-            [a.latency_ms, a.energy_j, a.memory_mb], atol=1e-5, rtol=1e-5)
+    for a, b, want in zip(loop, many, ref):
+        np.testing.assert_allclose(_phys(b), _phys(a), atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(_phys(b), want, atol=1e-5, rtol=1e-5)
         assert b.mig == a.mig and b.tpu_slice == a.tpu_slice
-        assert b.meta == a.meta              # order preserved across buckets
+        assert b.meta == a.meta              # order preserved across bins
 
 
 def test_predictions_are_graph_specific(dippm):
@@ -82,15 +97,18 @@ def test_predictions_are_graph_specific(dippm):
 def test_compiled_fn_cache_reuse(dippm):
     eng = PredictionEngine(dippm.params, dippm.cfg)
     graphs = [_graph(10, seed=i) for i in range(4)]
-    eng.predict_graphs(graphs)               # 4 graphs → one (32, 4) call
+    eng.predict_graphs(graphs)               # 4 graphs → one bin, P = 256
     assert eng.stats.cache_misses == 1
     assert eng.stats.cache_hits == 0
-    eng.predict_graphs(graphs)               # same shapes → pure cache hit
+    eng.predict_graphs(graphs)               # same shape → pure cache hit
     assert eng.stats.cache_misses == 1
     assert eng.stats.cache_hits == 1
-    eng.predict_graphs([_graph(50, seed=9)])  # new node bucket → miss
+    eng.predict_graphs([_graph(50, seed=9)])  # another size, same rung → hit
+    assert eng.stats.cache_misses == 1
+    assert eng.stats.cache_hits == 2
+    eng.predict_graphs([_graph(300, seed=9)])  # past the rung, P = 512: miss
     assert eng.stats.cache_misses == 2
-    assert eng.stats.graphs_predicted == 9
+    assert eng.stats.graphs_predicted == 10
 
 
 def test_empty_and_single_graph(dippm):
@@ -103,8 +121,9 @@ def test_empty_and_single_graph(dippm):
 
 
 def test_batch_padding_rows_do_not_leak(dippm):
-    """A chunk of 3 pads to batch bucket 4; the phantom row must not
-    perturb real predictions."""
+    """A bin of 3 graphs leaves 13 of its 16 graph slots (and most node
+    rows) as padding; the padding, and a 4th graph filling one of those
+    slots, must not perturb the real predictions."""
     eng = PredictionEngine(dippm.params, dippm.cfg)
     graphs = [_graph(12, seed=i) for i in range(3)]
     out3 = eng.predict_graphs(graphs)
@@ -115,9 +134,10 @@ def test_batch_padding_rows_do_not_leak(dippm):
 
 
 def test_memory_envelope_splits_large_buckets(dippm):
-    """With a tiny max_batch the engine must chunk, still in order."""
+    """With a tiny node budget (32 nodes, 2 graphs a bin) the engine
+    must split the graphs over several bins, still in order."""
     eng = PredictionEngine(dippm.params, dippm.cfg,
-                           EngineConfig(max_batch=2))
+                           EngineConfig(node_budget=32))
     graphs = [_graph(10, seed=i) for i in range(5)]
     out = eng.predict_graphs(graphs)
     assert eng.stats.batches_run == 3        # 2 + 2 + 1
@@ -129,9 +149,10 @@ def test_memory_envelope_splits_large_buckets(dippm):
 
 def test_warmup_precompiles(dippm):
     eng = PredictionEngine(dippm.params, dippm.cfg)
-    n = eng.warmup(node_buckets=(32, 64))
+    n = eng.warmup(rungs=(256, 512))
     assert n == 2
-    eng.predict_graphs([_graph(10, seed=0) for _ in range(64)])
+    eng.predict_graphs([_graph(10, seed=i) for i in range(16)])  # P = 256
+    eng.predict_graphs([_graph(300, seed=0)])                    # P = 512
     assert eng.stats.cache_misses == 2       # all served from warmup
 
 
@@ -160,71 +181,47 @@ def test_variant_grid_unknown_family():
         variant_grid("nope", {"batch": [1]})
 
 
-# ---- sparse message-passing engine -----------------------------------------
+# ---- one packed path, whatever the training layout -------------------------
 
-def test_sparse_engine_matches_dense(dippm):
-    """sparse_mp engine: same predictions, same order, no dense adj."""
-    cfg_s = PMGNSConfig(hidden=32, sparse_mp=True)
-    eng_s = PredictionEngine(dippm.params, cfg_s)
+@pytest.mark.parametrize("layout", ["dense", "sparse", "packed"])
+def test_engine_serves_packed_bins_whatever_the_layout(dippm, layout):
+    """A model trained in any layout is served on packed bins: its
+    predictions equal dense ``pmgns_apply`` on the same params, and every
+    shape it compiles is a rung of the packed ladder, all of which
+    ``warmup(rungs="all")`` compiles before traffic."""
+    eng = PredictionEngine(dippm.params, PMGNSConfig(hidden=32,
+                                                     layout=layout))
+    assert eng.cfg.resolved_layout == "packed"
+    ladder = packed_rung_ladder(eng.engine_cfg.node_budget)
+    assert eng.warmup(rungs="all") == len(ladder)
     sizes = [3, 40, 100, 7, 60, 90, 12]
-    graphs = [_graph(n, seed=i) for i, n in enumerate(sizes)]
-    dense_out = dippm.predict_many(graphs)
-    sparse_out = eng_s.predict_graphs(graphs)
-    for a, b in zip(dense_out, sparse_out):
-        np.testing.assert_allclose(
-            [b.latency_ms, b.energy_j, b.memory_mb],
-            [a.latency_ms, a.energy_j, a.memory_mb], atol=1e-5, rtol=1e-5)
+    samples = [sample_from_graph(_graph(n, seed=i))
+               for i, n in enumerate(sizes)]
+    np.testing.assert_allclose(eng.predict_samples(samples),
+                               _dense_reference(dippm.params, samples),
+                               atol=1e-5, rtol=1e-5)
+    assert eng.stats.cache_misses == len(ladder)
+    assert set(eng.layer_plans) == set(ladder)
 
-
-def test_sparse_engine_cache_keys_include_edge_bucket(dippm):
-    cfg_s = PMGNSConfig(hidden=32, sparse_mp=True)
-    eng = PredictionEngine(dippm.params, cfg_s)
-    assert eng.sparse
-    eng.predict_graphs([_graph(10, seed=i) for i in range(4)])
-    assert eng.stats.cache_misses == 1
-    # sparser/denser chunks up to the bucket's edge floor (~2 edges/node)
-    # share the warmed shape: 30-node chains reuse the 10-node compile
-    eng.predict_graphs([_graph(30, seed=9 + i) for i in range(4)])
-    assert eng.stats.cache_misses == 1
-    assert eng.stats.cache_hits >= 1
-    # a chunk denser than the floor escapes to a larger edge bucket → miss
-    def _dense_graph(seed):
-        g = _graph(30, seed=seed)
-        return OpGraph(nodes=g.nodes,
-                       edges=[(i, j) for i in range(30)
-                              for j in range(i + 1, 30) if (i + j) % 3],
-                       meta=dict(g.meta))
-    assert len(_dense_graph(0).edges) > 64   # past edge_bucket_for(2 · 32)
-    eng.predict_graphs([_dense_graph(s) for s in range(4)])
-    assert eng.stats.cache_misses == 2
-
-
-def test_sparse_warmup_precompiles(dippm):
-    cfg_s = PMGNSConfig(hidden=32, sparse_mp=True)
-    eng = PredictionEngine(dippm.params, cfg_s)
-    assert eng.warmup(node_buckets=(32,)) == 1
-
-
-# ---- packed block-diagonal engine ------------------------------------------
 
 def test_packed_engine_matches_dense(dippm):
-    """Packed engine: same predictions, same order, one flat node axis."""
+    """Packed engine: same predictions as dense ``pmgns_apply``, same
+    order, one flat node axis."""
     cfg_p = PMGNSConfig(hidden=32, layout="packed")
     eng_p = PredictionEngine(dippm.params, cfg_p)
     sizes = [3, 40, 100, 7, 60, 90, 12]
     graphs = [_graph(n, seed=i) for i, n in enumerate(sizes)]
-    dense_out = dippm.predict_many(graphs)
+    ref = _dense_reference(dippm.params,
+                           [sample_from_graph(g) for g in graphs])
     packed_out = eng_p.predict_graphs(graphs)
-    for a, b in zip(dense_out, packed_out):
-        np.testing.assert_allclose(
-            [b.latency_ms, b.energy_j, b.memory_mb],
-            [a.latency_ms, a.energy_j, a.memory_mb], atol=1e-5, rtol=1e-5)
-        assert b.meta == a.meta
+    for g, want, b in zip(graphs, ref, packed_out):
+        np.testing.assert_allclose(_phys(b), want, atol=1e-5, rtol=1e-5)
+        assert b.meta == g.meta
 
 
 def test_packed_engine_single_compiled_shape(dippm):
-    """Mixed node sizes that cost the bucketed engine several compiled
-    shapes all land on ONE packed budget shape."""
+    """Mixed node sizes across five node buckets all land on ONE packed
+    budget shape."""
     cfg_p = PMGNSConfig(hidden=32, layout="packed")
     eng = PredictionEngine(dippm.params, cfg_p)
     sizes = [3, 40, 100, 7, 60, 90, 12, 31, 33, 200, 500]   # 5 buckets
@@ -243,19 +240,23 @@ def test_packed_warmup_precompiles(dippm):
 
 
 def test_engine_stats_padding_waste(dippm):
-    """Packed waste must undercut the bucketed engine's on mixed sizes,
-    and both expose the counters the benchmark prints."""
+    """Packed waste must undercut padding each graph to its node bucket
+    on mixed sizes, and a dense-trained model's engine exposes the same
+    counters the benchmark prints (one path)."""
     cfg_p = PMGNSConfig(hidden=32, layout="packed")
     eng_p = PredictionEngine(dippm.params, cfg_p,
                              EngineConfig(node_budget=512))
-    eng_d = PredictionEngine(dippm.params, dippm.cfg)
-    graphs = [_graph(n, seed=i)
-              for i, n in enumerate([33, 33, 70, 70, 140, 9, 9, 9])]
+    eng_d = PredictionEngine(dippm.params, dippm.cfg,
+                             EngineConfig(node_budget=512))
+    sizes = [33, 33, 70, 70, 140, 9, 9, 9]
+    graphs = [_graph(n, seed=i) for i, n in enumerate(sizes)]
     eng_p.predict_graphs(graphs)
     eng_d.predict_graphs(graphs)
-    assert 0.0 < eng_p.stats.padding_waste_frac < 1.0
-    assert eng_p.stats.padding_waste_frac < eng_d.stats.padding_waste_frac
-    assert eng_p.stats.node_slots_real == sum([33, 33, 70, 70, 140, 9, 9, 9])
+    bucketed = 1.0 - sum(sizes) / sum(bucket_for(n) for n in sizes)
+    assert 0.0 < eng_p.stats.padding_waste_frac < bucketed
+    assert eng_p.stats.node_slots_real == sum(sizes)
+    assert (eng_d.stats.node_slots_total, eng_d.stats.node_slots_real) == \
+        (eng_p.stats.node_slots_total, eng_p.stats.node_slots_real)
 
 
 def test_plan_bins_partition_and_run_bin(dippm):
@@ -275,15 +276,6 @@ def test_plan_bins_partition_and_run_bin(dippm):
         ref = PredictionEngine(dippm.params, cfg).predict_samples(samples)
         np.testing.assert_allclose(out, ref, atol=1e-6, rtol=1e-6)
         assert eng.stats.graphs_predicted == len(samples)
-
-
-def test_run_bin_rejects_mixed_buckets(dippm):
-    from repro.core.batching import sample_from_graph
-    eng = PredictionEngine(dippm.params, dippm.cfg)
-    mixed = [sample_from_graph(_graph(5, seed=0)),
-             sample_from_graph(_graph(60, seed=1))]
-    with pytest.raises(ValueError, match="single-bucket"):
-        eng.run_bin(mixed)
 
 
 def test_run_bin_threadsafe_concurrent_callers(dippm):

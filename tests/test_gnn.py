@@ -126,7 +126,7 @@ def _paired_batches(B=6, N=24, F=32, sdim=5, density=0.08, seed=3):
 def test_sparse_mp_matches_dense(variant):
     """Every variant: sparse edge-list path == dense adjacency path."""
     cfg_d = PMGNSConfig(variant=variant, hidden=32)
-    cfg_s = PMGNSConfig(variant=variant, hidden=32, sparse_mp=True)
+    cfg_s = PMGNSConfig(variant=variant, hidden=32, layout="sparse")
     params = pmgns_init(jax.random.PRNGKey(0), cfg_d)
     dense, sparse = _paired_batches()
     od = pmgns_apply(params, cfg_d, dense)
@@ -138,8 +138,8 @@ def test_sparse_mp_matches_dense(variant):
 
 @pytest.mark.parametrize("variant", ["graphsage", "gcn", "gat", "gin"])
 def test_sparse_pallas_matches_sparse_ref(variant, monkeypatch):
-    cfg_ref = PMGNSConfig(variant=variant, hidden=32, sparse_mp=True)
-    cfg_pal = PMGNSConfig(variant=variant, hidden=32, sparse_mp=True,
+    cfg_ref = PMGNSConfig(variant=variant, hidden=32, layout="sparse")
+    cfg_pal = PMGNSConfig(variant=variant, hidden=32, layout="sparse",
                           use_pallas=True)
     params = pmgns_init(jax.random.PRNGKey(1), cfg_ref)
     _, sparse = _paired_batches(seed=5)
@@ -153,7 +153,7 @@ def test_sparse_pallas_matches_sparse_ref(variant, monkeypatch):
 
 def test_sparse_mp_is_differentiable():
     """Training runs on the sparse path: grads exist and are finite."""
-    cfg = PMGNSConfig(hidden=32, sparse_mp=True)
+    cfg = PMGNSConfig(hidden=32, layout="sparse")
     params = pmgns_init(jax.random.PRNGKey(1), cfg)
     _, sparse = _paired_batches(seed=7)
     y = jnp.asarray(RNG.random((6, 3)) * 100 + 1, jnp.float32)
@@ -168,12 +168,13 @@ def test_sparse_mp_is_differentiable():
     assert any(float(jnp.abs(l).max()) > 0 for l in leaves)
 
 
-@pytest.mark.parametrize("sparse_mp", [False, True])
-def test_gat_empty_neighborhood_no_nan(sparse_mp):
+@pytest.mark.parametrize("sparse", [False, True])
+def test_gat_empty_neighborhood_no_nan(sparse):
     """Regression: a graph whose nodes have no incoming edges at all
     (every destination row fully masked) must predict finite values on
     both layouts — the all-padding softmax row is the NaN risk."""
-    cfg = PMGNSConfig(variant="gat", hidden=32, sparse_mp=sparse_mp)
+    cfg = PMGNSConfig(variant="gat", hidden=32,
+                      layout="sparse" if sparse else "dense")
     params = pmgns_init(jax.random.PRNGKey(0), cfg)
     B, N = 2, 8
     batch = {
@@ -181,7 +182,7 @@ def test_gat_empty_neighborhood_no_nan(sparse_mp):
         "mask": jnp.ones((B, N), jnp.float32),
         "static": jnp.asarray(RNG.standard_normal((B, 5)), jnp.float32),
     }
-    if sparse_mp:
+    if sparse:
         batch["edges"] = jnp.zeros((B, 4, 2), jnp.int32)
         batch["edge_mask"] = jnp.zeros((B, 4), jnp.float32)
     else:
@@ -207,7 +208,7 @@ def test_gat_edgeless_graph_inside_mixed_batch():
     dense = dict(dense, adj=jnp.asarray(adj))
     sparse = dict(sparse, edge_mask=jnp.asarray(emask))
     cfg_d = PMGNSConfig(variant="gat", hidden=32)
-    cfg_s = PMGNSConfig(variant="gat", hidden=32, sparse_mp=True)
+    cfg_s = PMGNSConfig(variant="gat", hidden=32, layout="sparse")
     params = pmgns_init(jax.random.PRNGKey(2), cfg_d)
     od = pmgns_apply(params, cfg_d, dense)
     os_ = pmgns_apply(params, cfg_s, sparse)
@@ -217,13 +218,13 @@ def test_gat_edgeless_graph_inside_mixed_batch():
 
 
 def test_layout_mismatch_raises():
-    cfg_s = PMGNSConfig(hidden=32, sparse_mp=True)
+    cfg_s = PMGNSConfig(hidden=32, layout="sparse")
     cfg_d = PMGNSConfig(hidden=32)
     params = pmgns_init(jax.random.PRNGKey(0), cfg_d)
     dense, sparse = _paired_batches(B=2)
-    with pytest.raises(ValueError, match="sparse_mp=True"):
+    with pytest.raises(ValueError, match="layout='sparse'"):
         pmgns_apply(params, cfg_s, dense)
-    with pytest.raises(ValueError, match="sparse_mp=False"):
+    with pytest.raises(ValueError, match="layout='dense'"):
         pmgns_apply(params, cfg_d, sparse)
 
 

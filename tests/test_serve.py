@@ -1,5 +1,6 @@
 """Serving semantics: micro-batching, FIFO futures, admission control,
 warmup, and the versioned artifact format (``repro.serve``)."""
+import dataclasses
 import os
 import pickle
 import threading
@@ -229,10 +230,16 @@ def test_engine_warmup_default_still_single_rung(packed_dippm):
     assert eng2.warmup(rungs="all") == 5
 
 
-def test_warmup_rungs_rejected_on_bucketed_engine(dense_dippm):
-    eng = PredictionEngine(dense_dippm.params, dense_dippm.cfg)
-    with pytest.raises(ValueError, match="packed"):
-        eng.warmup(rungs="all")
+def test_service_warmup_on_dense_trained_model_compiles_the_ladder(
+        dense_dippm):
+    """A dense-trained model is served on packed bins: the service's
+    warmup compiles the whole rung ladder, as for a packed-trained one."""
+    svc = PredictionService(dense_dippm.params, dense_dippm.cfg)
+    try:
+        assert svc.expected_rungs() == len(packed_rung_ladder())
+        assert svc.warmup() == svc.expected_rungs()
+    finally:
+        svc.close()
 
 
 # ---- serve config plumbing -------------------------------------------------
@@ -315,6 +322,27 @@ def test_legacy_pickle_fallback_warns(dense_dippm, tmp_path):
     assert (back.predict_graph(g).latency_ms
             == pytest.approx(dense_dippm.predict_graph(g).latency_ms,
                              rel=1e-6))
+
+
+def test_artifact_with_sparse_mp_alias_keeps_its_layout(dense_dippm,
+                                                        tmp_path):
+    """A config saved with the retired ``sparse_mp`` alias set and
+    ``layout`` ``"auto"`` loads as the sparse layout it trained in."""
+    import json
+    path = str(tmp_path / "model.npz")
+    dense_dippm.save(path)
+    with np.load(path, allow_pickle=False) as z:
+        doc = json.loads(bytes(z["__dippm_artifact__"]).decode())
+        arrays = {k: z[k] for k in z.files if k != "__dippm_artifact__"}
+    assert doc["cfg"]["layout"] == "auto"
+    for alias, layout in ((True, "sparse"), (False, "auto")):
+        doc["cfg"]["sparse_mp"] = alias
+        header = np.frombuffer(json.dumps(doc).encode(), np.uint8)
+        with open(path, "wb") as f:
+            np.savez(f, __dippm_artifact__=header, **arrays)
+        _, cfg, _ = load_artifact(path)
+        assert cfg == PMGNSConfig(**dict(
+            dataclasses.asdict(dense_dippm.cfg), layout=layout))
 
 
 def test_artifact_rejects_newer_schema(dense_dippm, tmp_path):

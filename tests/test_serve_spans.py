@@ -107,7 +107,7 @@ def test_first_call_of_a_shape_is_a_compile_span(layout, tmp_path):
     cfg = PMGNSConfig(hidden=16, layout=layout)
     eng = PredictionEngine(pmgns_init(jax.random.PRNGKey(1), cfg), cfg)
     chunk = [sample_from_graph(g, buckets=eng.engine_cfg.buckets)
-             for g in (GRAPHS[0], GRAPHS[3])]     # one node bucket
+             for g in (GRAPHS[0], GRAPHS[3])]
 
     def twice():
         return eng.run_bin(chunk), eng.run_bin(chunk)
@@ -121,11 +121,9 @@ def test_first_call_of_a_shape_is_a_compile_span(layout, tmp_path):
     plan = {"kernel_layers": 0, "segment_layers": 0}    # no Pallas here
     assert stage[4]["graphs"] == 2 and run[4] == {"graphs": 2, **plan}
     assert {k: compile_[4][k] for k in plan} == plan
-    if layout == "packed":
-        assert set(stage[4]) == set(compile_[4]) - set(plan) | {"graphs"} \
-            == {"graphs", "p", "q", "g"}
-    else:
-        assert set(compile_[4]) - set(plan) == {"nodes", "edges", "batch"}
+    # every layout is served on packed bins
+    assert set(stage[4]) == set(compile_[4]) - set(plan) | {"graphs"} \
+        == {"graphs", "p", "q", "g"}
     fetch = events[5]
     assert run[2] <= fetch[2] and \
         fetch[2] + fetch[3] <= run[2] + run[3]     # fetch inside run

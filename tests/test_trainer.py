@@ -219,11 +219,11 @@ def test_resume_from_empty_dir_starts_fresh(tmp_path):
 # ---- sparse message passing ------------------------------------------------
 
 def test_sparse_scan_epoch_matches_dense():
-    """A full scan-compiled epoch with sparse_mp=True reproduces the dense
+    """A full scan-compiled epoch with layout="sparse" reproduces the dense
     path's loss trajectory and parameters (identical schedule, no [B,N,N]
     adjacency anywhere in the segments)."""
     samples = _synth_samples(24, seed=12)
-    cfg_sparse = dataclasses.replace(CFG, sparse_mp=True)
+    cfg_sparse = dataclasses.replace(CFG, layout="sparse")
     common = dict(epochs=2, batch_size=8, lr=3e-3, seed=0)
     p_dense, h_dense = train_pmgns(CFG, samples, (),
                                    TrainConfig(mode="scan", **common))
@@ -242,7 +242,7 @@ def test_sparse_scan_epoch_matches_dense():
 def test_sparse_data_parallel_trains():
     """sparse segments shard over the batch axis like dense ones."""
     samples = _synth_samples(24, seed=13)
-    cfg_sparse = dataclasses.replace(CFG, sparse_mp=True)
+    cfg_sparse = dataclasses.replace(CFG, layout="sparse")
     params, hist = train_pmgns(
         cfg_sparse, samples, (),
         TrainConfig(epochs=3, batch_size=8, lr=3e-3, seed=0,
@@ -258,7 +258,7 @@ def test_packed_scan_epoch_matches_sparse():
     Dropout off: packed activations have different shapes, so train-mode
     dropout masks are drawn differently by construction."""
     samples = _synth_samples(24, seed=15)
-    cfg_sparse = dataclasses.replace(CFG, sparse_mp=True, dropout=0.0)
+    cfg_sparse = dataclasses.replace(CFG, layout="sparse", dropout=0.0)
     cfg_packed = dataclasses.replace(CFG, layout="packed", dropout=0.0)
     common = dict(epochs=2, batch_size=8, lr=3e-3, seed=0)
     p_s, h_s = train_pmgns(cfg_sparse, samples, (),
@@ -321,7 +321,7 @@ def test_packed_data_parallel_raises():
 
 def test_sparse_eval_and_predict_batch():
     samples = _synth_samples(10, seed=14)
-    cfg_sparse = dataclasses.replace(CFG, sparse_mp=True)
+    cfg_sparse = dataclasses.replace(CFG, layout="sparse")
     params = pmgns_init(jax.random.PRNGKey(0), CFG)
     preds_d = predict_batch(params, CFG, samples)
     preds_s = predict_batch(params, cfg_sparse, samples)

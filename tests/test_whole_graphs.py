@@ -1,11 +1,11 @@
-"""Graphs over the top node bucket: whole on the packed path, cut where a
-bucketed layout pads to a bucket.
+"""Graphs over the top node bucket: whole in every prediction, cut where a
+training layout pads to a bucket.
 
-The packed serving path runs such a graph as a lone bin at power-of-two
+The engine runs such a graph as a lone packed bin at power-of-two
 shapes, with each message-passing layer planned onto the fused kernel or
-the lax segment composition by its VMEM state; the bucketed engine path,
-the dataset builder and the trainer cut it to the top bucket, each as a
-step of its own.
+the lax segment composition by its VMEM state, whatever layout the model
+was trained in; the dataset builder and the trainer cut it to the top
+bucket, each as a step of its own.
 """
 import dataclasses
 import gzip
@@ -121,21 +121,37 @@ def test_cut_to_bucket_keeps_the_heaviest_nodes():
     assert cut_to_bucket(fits) is fits
 
 
-# -- the cut where a bucketed layout needs it --------------------------------
-
 @pytest.mark.parametrize("layout", ["dense", "sparse"])
-def test_bucketed_engine_cuts_at_the_top_bucket(layout):
+def test_bucketed_layout_model_serves_the_graph_whole(layout):
+    """A model trained in a bucketed layout serves a 1,300-node graph
+    whole: its answer equals a packed-layout engine's on the same params,
+    differs from the answer on the graph cut to the top bucket, and
+    ``DIPPM.predict_graph``'s serving record counts every node."""
+    from repro.core.predictor import DIPPM
     cfg = PMGNSConfig(hidden=16, layout=layout)
-    eng = PredictionEngine(pmgns_init(jax.random.PRNGKey(4), cfg), cfg)
-    whole = sample_from_graph(_chain(1300, seed=5, extra=200))
+    params = pmgns_init(jax.random.PRNGKey(4), cfg)
+    eng = PredictionEngine(params, cfg)
+    g = _chain(1300, seed=5, extra=200)
+    whole = sample_from_graph(g)
     small = sample_from_graph(_chain(900, seed=6))
     bins = eng.plan_bins([whole, small])
     assert sorted(i for b in bins for i in b) == [0, 1]
-    assert len(bins) == 1                    # both in the 1,024 bucket
     got = eng.predict_samples([whole, small])
-    want = eng.predict_samples([cut_to_bucket(whole), small])
-    np.testing.assert_array_equal(got, want)
-    assert eng.bin_shape([cut_to_bucket(whole)]) == (1024, 1)
+    packed = PredictionEngine(params,
+                              dataclasses.replace(cfg, layout="packed"))
+    np.testing.assert_allclose(got, packed.predict_samples([whole, small]),
+                               atol=1e-5, rtol=1e-5)
+    cut = eng.predict_samples([cut_to_bucket(whole)])
+    assert not np.allclose(got[:1], cut, atol=1e-3, rtol=1e-3)
+    pred = DIPPM.from_params(params, cfg).predict_graph(g)
+    assert pred.serving["nodes"] == 1300
+    assert pred.serving["bin"] == eng.bin_shape([whole])
+    np.testing.assert_allclose(
+        [pred.latency_ms, pred.energy_j, pred.memory_mb], got[0],
+        atol=1e-5, rtol=1e-5)
+
+
+# -- the cut where a training layout needs it --------------------------------
 
 
 def test_dataset_builder_cuts_at_the_top_bucket():
